@@ -69,7 +69,7 @@ def kernel_rows(out_dir: Path | None = None) -> dict:
     u = jax.random.normal(ks[3], (B, T, D))
     A = -jnp.exp(jax.random.normal(ks[4], (D, N)) * 0.5)
     ref, us = timed(lambda: jax.block_until_ready(ssm_scan_reference(dt, Bc, Cc, u, A)[0]))
-    out = ssm_scan(dt, Bc, Cc, u, A, impl="interpret", blk_t=32, blk_d=64)
+    out = ssm_scan(dt, Bc, Cc, u, A, impl="interpret", blk_t=32, blk_d=128)
     record("ssm_scan", us, _err(out, ref))
 
     # rmsnorm
@@ -84,7 +84,7 @@ def kernel_rows(out_dir: Path | None = None) -> dict:
     arr = jnp.asarray(np.cumsum(rng.exponential(0.1, (16, 1024)), axis=1), jnp.float32)
     svc = jnp.asarray(rng.exponential(0.05, (16, 1024)), jnp.float32)
     ref, us = timed(lambda: jax.block_until_ready(lindley_scan(arr, svc, impl="xla")))
-    out = lindley_scan(arr, svc, impl="interpret", blk_b=8, blk_t=256)
+    out = lindley_scan(arr, svc, impl="interpret", blk_b=16, blk_t=256)
     record("lindley_scan", us, _err(out, ref))
 
     # decision scan (the cluster simulator's per-epoch staggered decide step)
@@ -93,7 +93,7 @@ def kernel_rows(out_dir: Path | None = None) -> dict:
     ref, us = timed(lambda: jax.block_until_ready(
         decision_scan(costs, coh, hysteresis=0.15, stagger=4, impl="xla")))
     out = decision_scan(costs, coh, hysteresis=0.15, stagger=4,
-                        impl="interpret", blk_n=8, blk_t=64)
+                        impl="interpret", blk_n=16, blk_t=64)
     record("decision_scan", us, _err(out, ref))
 
     if out_dir is not None:

@@ -152,4 +152,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.jaxenv import enable_compilation_cache
+
+    enable_compilation_cache()
     raise SystemExit(main())

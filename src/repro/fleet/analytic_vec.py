@@ -9,8 +9,8 @@ bisection for crossover points. One jitted call evaluates the whole fleet —
 millions of scenarios per second on a laptop CPU, every row bit-comparable
 (<= 1e-9 relative) to ``scenario.analytic()`` on the same spec.
 
-All math runs in float64 inside a scoped ``jax.experimental.enable_x64()``
-context so the closed forms keep numpy-double semantics without flipping the
+All math runs in float64 inside a scoped ``repro.jaxenv.x64()`` context so
+the closed forms keep numpy-double semantics without flipping the
 process-global x64 switch out from under the float32 model/kernel stack.
 Unstable operating points yield ``inf``, exactly as the kernel layer does.
 
@@ -25,9 +25,10 @@ from dataclasses import dataclass
 from functools import partial
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
+
+from repro.jaxenv import x64
 
 from .batch import ScenarioBatch
 
@@ -88,7 +89,7 @@ def mmk_wait_erlang_vec(lam, mu, k, *, max_k: int = 64):
             f"k={np.max(k_np)} exceeds max_k={max_k}; raise max_k or the "
             "truncated Erlang-B sum would be silently wrong")
     out_shape = np.broadcast_shapes(lam_np.shape, mu_np.shape, k_np.shape)
-    with jax.experimental.enable_x64():
+    with x64():
         out = _mmk_wait_erlang_impl(
             jnp.atleast_1d(jnp.asarray(lam_np)),
             jnp.atleast_1d(jnp.asarray(mu_np)),
@@ -221,7 +222,7 @@ class FleetPrediction:
 
 def fleet_analytic(batch: ScenarioBatch) -> FleetPrediction:
     """Closed-form per-strategy latency of every scenario, one jitted call."""
-    with jax.experimental.enable_x64():
+    with x64():
         arrays = {k: jnp.asarray(v) for k, v in batch.arrays().items()}
         t_dev, t_edge, best = _fleet_analytic_jit(arrays)
         return FleetPrediction(
@@ -353,7 +354,7 @@ def fleet_crossover(
     if batch.max_edges == 0 or not 0 <= edge < batch.max_edges:
         raise ValueError(f"edge index {edge} out of range for batch with "
                          f"{batch.max_edges} edge slots")
-    with jax.experimental.enable_x64():
+    with x64():
         c = {k: jnp.asarray(v) for k, v in batch.arrays().items()}
         b = batch.size
         if axis == "bandwidth":

@@ -37,7 +37,6 @@ from functools import partial
 from typing import Mapping, Sequence
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,6 +52,7 @@ from repro.core.scenario import (
 )
 from repro.core.simulation import steady_slice
 from repro.core.tail import euler_grow_iters, resolve_tail_method
+from repro.jaxenv import x64
 
 from .analytic_vec import (
     _device_latency_vec,
@@ -311,7 +311,7 @@ def predict_decisions(
             raise ValueError(f"slo_quantile must be in (0, 1), got {slo_quantile}")
         tail_method = resolve_tail_method(slo_quantile, tail_method)
     cst = _spec_arrays(spec)
-    with jax.experimental.enable_x64():
+    with x64():
         c = _as_jnp(cst)
         lam_hat = jnp.atleast_1d(jnp.asarray(lam_hat, dtype=jnp.float64))
         if lam_hat.shape[0] != spec.n_clients:
@@ -362,7 +362,7 @@ def predict_terms(
     reconstructs closed-loop decision audits from.
     """
     cst = _spec_arrays(spec)
-    with jax.experimental.enable_x64():
+    with x64():
         c = _as_jnp(cst)
         lam_hat = jnp.atleast_1d(jnp.asarray(lam_hat, dtype=jnp.float64))
         if lam_hat.shape[0] != spec.n_clients:
@@ -554,7 +554,6 @@ def _closed_loop_scan_shardmap(cst, bw_true, lam_true, exo_true, n_req, *,
     as the only cross-device collective per epoch. Same math as
     ``_closed_loop_scan_blocked`` (its single-host oracle) — the decision
     loop is embarrassingly parallel in clients given lagged load reports."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n = lam_true.shape[1]
@@ -564,11 +563,11 @@ def _closed_loop_scan_shardmap(cst, bw_true, lam_true, exo_true, n_req, *,
                   bw_alpha=bw_alpha, bg_alpha=bg_alpha, hysteresis=hysteresis,
                   slo_q=slo_q, tail_method=tail_method, axis_name="shards")
     cols = P(None, "shards")
-    fn = shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P("shards"), P("shards"), cols, cols, P(), cols),
         out_specs=(cols, P(), cols, cols, P(None, "shards", None), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)(cst, cst["lam_spec"], cohort, bw_true, lam_true,
                        exo_true, n_req)
 
@@ -813,7 +812,7 @@ def simulate_cluster(
         name: parse_policy(name, e_n) for name in policies if name != "adaptive"
     }
 
-    with jax.experimental.enable_x64():
+    with x64():
         cst_j = _as_jnp(cst)
         bw_j = jnp.asarray(traces.bandwidth_Bps)
         lam_j = jnp.asarray(traces.arrival_rate)
@@ -1000,7 +999,7 @@ def solve_equilibrium(
     if exo.shape != (e_n,):
         raise ScenarioError("exo_rates", f"expected shape ({e_n},), got {exo.shape}")
 
-    with jax.experimental.enable_x64():
+    with x64():
         cst_j = _as_jnp(cst)
         choices = np.full(n, ON_DEVICE, dtype=np.int32)
         seen = {choices.tobytes()}

@@ -41,17 +41,23 @@ entry). The <= 1e-8 scalar-vs-vec agreement gate therefore requires both
 sides to walk the IDENTICAL evaluation sequence. ``quantile_euler_vec``
 replays ``core.tail._quantile_euler`` phase for phase — same start
 ``max(2 * mean, 1e-12)``, same doubling schedule, same bisection midpoints,
-same Newton formula and safeguard — on a CDF that is arithmetically identical
+same Newton formula and safeguard — on a CDF computed by the same formulas
 term for term (``exp(where(c, a, b)) == where(c, exp(a), exp(b))``), so the
-two sides agree to float-noise (~1e-14), and the differential harness gates
-it at <= 1e-8 (``tail-euler-vec`` check).
+two sides agree to float-noise (~1e-11 over the golden corpus), and the
+differential harness gates it at <= 1e-8 (``tail-euler-vec`` check). The
+three phases share one loop body, so the contour is traced and compiled once.
+
+The contour is carried as paired real/imaginary float64 arrays: complex
+``exp``, ``log``, multiply and divide are spelled out in real arithmetic
+(``_cexp``, ``_clog``, ``_cmul``, ``_cdiv``), so no complex value reaches the
+compiler. TPU compilers refuse complex128, and this keeps the exact inversion
+on the chip rather than on the host.
 
 A Pallas kernel variant was considered and skipped: the inner loop is
-dominated by complex ``exp``/``log`` over a (rows, 27)-point contour, which
-XLA already fuses into a handful of elementwise kernels; on CPU (interpret
-mode) a hand-written kernel only adds overhead, and the transcendental mix
-leaves no tiling structure for a TPU kernel to exploit beyond what the fused
-elementwise path gets.
+dominated by transcendentals over a (rows, 27)-point contour, which XLA
+already fuses into a handful of elementwise kernels, and the transcendental
+mix leaves no tiling structure for a TPU kernel to exploit beyond what the
+fused elementwise path gets.
 
 Import direction: this module must not import ``tail_vec`` (which routes its
 euler method here) — the shared station-dict helpers it needs live locally.
@@ -61,6 +67,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tail import (
     EULER_A,
@@ -80,9 +87,41 @@ __all__ = ["cdf_pdf_vec", "quantile_euler_vec"]
 _INF = jnp.inf
 _TINY = 1e-300
 
+# contour index k, its Euler sign (k = 0 term halved) and the summation window
+_KS = np.arange(EULER_N + EULER_M + 1, dtype=np.float64)
+_SIGN = np.where(_KS == 0, 0.5, 1.0) * (-1.0) ** _KS
+_WINDOW = slice(EULER_N, EULER_N + EULER_M + 1)
+_ONE = (1.0, 0.0)
+
+
+# complex arithmetic on (re, im) pairs of float64 arrays
+
+
+def _cmul(a, b):
+    return a[0] * b[0] - a[1] * b[1], a[0] * b[1] + a[1] * b[0]
+
+
+def _cdiv(a, b):
+    den = b[0] * b[0] + b[1] * b[1]
+    return (a[0] * b[0] + a[1] * b[1]) / den, (a[1] * b[0] - a[0] * b[1]) / den
+
+
+def _cexp(z):
+    mag = jnp.exp(z[0])
+    return mag * jnp.cos(z[1]), mag * jnp.sin(z[1])
+
+
+def _clog(z):
+    return 0.5 * jnp.log(z[0] * z[0] + z[1] * z[1]), jnp.arctan2(z[1], z[0])
+
+
+def _cwhere(c, a, b):
+    return jnp.where(c, a[0], b[0]), jnp.where(c, a[1], b[1])
+
 
 def _slot_service_lst(kind, mean, var, theta, hint):
-    """Complex LST E[e^{-theta S}] for one slot's service distribution.
+    """LST E[e^{-theta S}] for one slot's service distribution, as a
+    (re, im) pair.
 
     ``hint`` is the slot's static service-kind hint: ``"exp"`` / ``"nic"``
     mean every row's ``kind`` is KIND_EXP by construction (NIC slots, or a
@@ -95,33 +134,37 @@ def _slot_service_lst(kind, mean, var, theta, hint):
     one complex exp + one complex log instead of two and one). ``mean == 0``
     is the inert factor 1, as everywhere in the tail layer.
     """
+    tr, ti = theta
     if hint in ("exp", "nic"):
-        out = 1.0 / (1.0 + theta * mean)
-        return jnp.where(mean > 0, out, jnp.ones_like(out))
+        out = _cdiv(_ONE, (1.0 + tr * mean, ti * mean))
+        return _cwhere(mean > 0, out, _ONE)
     if hint == "det":
-        out = jnp.exp(-theta * mean)
-        return jnp.where(mean > 0, out, jnp.ones_like(out))
-    exp_ = 1.0 / (1.0 + theta * mean)
+        out = _cexp((-tr * mean, -ti * mean))
+        return _cwhere(mean > 0, out, _ONE)
+    exp_ = _cdiv(_ONE, (1.0 + tr * mean, ti * mean))
     gamma_real = var > GAMMA_DET_CV2 * mean * mean  # tail.GAMMA_DET_CV2 cutoff
     safe_mean = jnp.where(mean > 0, mean, 1.0)
     safe_var = jnp.where(gamma_real, var, 1.0)
     shape = safe_mean * safe_mean / safe_var
     scale = safe_var / safe_mean
     use_gamma = (kind == KIND_GAMMA) & gamma_real
-    expo = jnp.where(use_gamma, -shape * jnp.log(1.0 + theta * scale),
-                     -theta * mean)
-    out = jnp.where(kind == KIND_EXP, exp_, jnp.exp(expo))
-    return jnp.where(mean > 0, out, jnp.ones_like(out))
+    lg = _clog((1.0 + tr * scale, ti * scale))
+    expo = _cwhere(use_gamma, (-shape * lg[0], -shape * lg[1]),
+                   (-tr * mean, -ti * mean))
+    out = _cwhere(kind == KIND_EXP, exp_, _cexp(expo))
+    return _cwhere(mean > 0, out, _ONE)
 
 
 def _total_lst_slots(st, theta, slot_kinds):
     """Product of per-slot sojourn transforms ``W* Sf*`` at ``theta``
-    (trailing contour axis K). The slot loop is unrolled in Python — S is 1
-    (device) or 3 (offload tandem) — so each slot's static hint can prune its
-    traced branches independently. Hint ``"nic"`` additionally asserts
-    ``wmean == fmean`` (true for every ``nic_station``), letting the wait
-    factor reuse the full-service LST instead of re-evaluating it.
+    (trailing contour axis K), as a (re, im) pair. The slot loop is unrolled
+    in Python — S is 1 (device) or 3 (offload tandem) — so each slot's
+    static hint can prune its traced branches independently. Hint ``"nic"``
+    additionally asserts ``wmean == fmean`` (true for every
+    ``nic_station``), letting the wait factor reuse the full-service LST
+    instead of re-evaluating it.
     """
+    tr, ti = theta
     n_slots = st["lam"].shape[-1]
     if slot_kinds is None:
         slot_kinds = (None,) * n_slots
@@ -138,10 +181,11 @@ def _total_lst_slots(st, theta, slot_kinds):
         else:
             sw = _slot_service_lst(st["wkind"][..., s, None], wmean,
                                    st["wvar"][..., s, None], theta, hint)
-        w = (1.0 - rho) * theta / (theta - lam * (1.0 - sw))
-        w = jnp.where(rho > 0, w, jnp.ones_like(w))
-        fac = w * f
-        out = fac if out is None else out * fac
+        # W*(theta) = (1 - rho) theta / (theta - lam (1 - Sw*(theta)))
+        w = _cdiv(((1.0 - rho) * tr, (1.0 - rho) * ti),
+                  (tr - lam * (1.0 - sw[0]), ti + lam * sw[1]))
+        fac = _cmul(_cwhere(rho > 0, w, _ONE), f)
+        out = fac if out is None else _cmul(out, fac)
     return out
 
 
@@ -159,45 +203,36 @@ def _sojourn_mean_vec(st):
     return jnp.sum(w + st["fmean"], axis=-1)
 
 
+def _contour(st, t, slot_kinds):
+    """theta_k = (A + 2 pi i k) / (2t) and T*(theta_k), both (re, im)."""
+    tt = 2.0 * t[..., None]
+    theta = (EULER_A / tt, (2.0 * jnp.pi * jnp.asarray(_KS)) / tt)
+    return theta, _total_lst_slots(st, theta, slot_kinds)
+
+
+def _euler_sum(terms, t):
+    """Abate-Whitt Euler summation of the real contour terms at ``t``."""
+    partial = jnp.cumsum(jnp.asarray(_SIGN) * terms, axis=-1)
+    return jnp.exp(EULER_A / 2.0) / t * (partial[..., _WINDOW]
+                                         @ jnp.asarray(_EULER_WEIGHTS))
+
+
 def cdf_pdf_vec(st, t, slot_kinds=None):
     """(CDF, PDF) of the composed sojourn at ``t``, one contour evaluation.
 
     Abate-Whitt inversion applies to any transform on the same contour
     ``theta_k = (A + 2 pi i k) / (2t)``: the CDF's transform is
     ``T*(theta)/theta``, the density's is ``T*(theta)`` itself. Sharing the
-    ``T*`` evaluations is what makes Newton's derivative free. Arithmetic is
-    term-for-term identical to the scalar ``core.tail._cdf_pdf`` on the same
-    station fields; the PDF is clipped at 0 (inversion noise can dip slightly
-    negative in flat regions — the safeguard treats a zero derivative as
-    "fall back to bisection").
+    ``T*`` evaluations is what makes Newton's derivative free. Arithmetic
+    follows the scalar ``core.tail._cdf_pdf`` on the same station fields,
+    in real (re, im) pairs; the PDF is clipped at 0 (inversion noise can dip
+    slightly negative in flat regions — the safeguard treats a zero
+    derivative as "fall back to bisection").
     """
-    ks = jnp.arange(EULER_N + EULER_M + 1, dtype=jnp.float64)
-    theta = (EULER_A + 2j * jnp.pi * ks) / (2.0 * t[..., None])
-    vals = _total_lst_slots(st, theta, slot_kinds)
-    sign = jnp.where(ks == 0, 0.5, 1.0) * ((-1.0) ** ks)
-    weights = jnp.asarray(_EULER_WEIGHTS)
-    scale = jnp.exp(EULER_A / 2.0) / t
-    cdf_part = jnp.cumsum(sign * (vals / theta).real, axis=-1)
-    pdf_part = jnp.cumsum(sign * vals.real, axis=-1)
-    window = slice(EULER_N, EULER_N + EULER_M + 1)
-    cdf = jnp.clip(scale * (cdf_part[..., window] @ weights), 0.0, 1.0)
-    pdf = jnp.maximum(scale * (pdf_part[..., window] @ weights), 0.0)
+    theta, vals = _contour(st, t, slot_kinds)
+    cdf = jnp.clip(_euler_sum(_cdiv(vals, theta)[0], t), 0.0, 1.0)
+    pdf = jnp.maximum(_euler_sum(vals[0], t), 0.0)
     return cdf, pdf
-
-
-def _cdf_vec(st, t, slot_kinds=None):
-    """CDF only — skips the density's cumsum/contraction for the grow and
-    bisect phases (the expensive part, the ``T*`` products, is shared either
-    way, so this changes cost, never values)."""
-    ks = jnp.arange(EULER_N + EULER_M + 1, dtype=jnp.float64)
-    theta = (EULER_A + 2j * jnp.pi * ks) / (2.0 * t[..., None])
-    vals = _total_lst_slots(st, theta, slot_kinds)
-    sign = jnp.where(ks == 0, 0.5, 1.0) * ((-1.0) ** ks)
-    weights = jnp.asarray(_EULER_WEIGHTS)
-    scale = jnp.exp(EULER_A / 2.0) / t
-    cdf_part = jnp.cumsum(sign * (vals / theta).real, axis=-1)
-    window = slice(EULER_N, EULER_N + EULER_M + 1)
-    return jnp.clip(scale * (cdf_part[..., window] @ weights), 0.0, 1.0)
 
 
 def quantile_euler_vec(st, q, slot_kinds=None, grow_iters=None):
@@ -222,33 +257,27 @@ def quantile_euler_vec(st, q, slot_kinds=None, grow_iters=None):
     finite = jnp.isfinite(mean)
     safe_mean = jnp.where(finite, mean, 1.0)
     hi0 = jnp.maximum(2.0 * safe_mean, 1e-12)
+    n_search = grow_iters + EULER_BISECT_ITERS
 
-    def grow(_, hi):
-        return jnp.where(_cdf_vec(st, hi, slot_kinds) < q, hi * 2.0, hi)
-
-    hi = jax.lax.fori_loop(0, grow_iters, grow, hi0)
-    # if the bracket grew, the last doubled-from point hi/2 is a known
-    # below-q evaluation — one free bisection
-    lo = jnp.where(hi > hi0, 0.5 * hi, 0.0)
-
-    def bisect(_, carry):
-        lo, hi = carry
-        mid = 0.5 * (lo + hi)
-        below = _cdf_vec(st, mid, slot_kinds) < q
-        return jnp.where(below, mid, lo), jnp.where(below, hi, mid)
-
-    lo, hi = jax.lax.fori_loop(0, EULER_BISECT_ITERS, bisect, (lo, hi))
-    t = 0.5 * (lo + hi)
-
-    def newton(_, carry):
+    # One loop over all three phases, so the contour is traced (and
+    # compiled) once: iteration i evaluates F and f at the phase's point.
+    def step(i, carry):
         lo, hi, t = carry
-        cdf, pdf = cdf_pdf_vec(st, t, slot_kinds)
+        grow, search = i < grow_iters, i < n_search
+        mid = 0.5 * (lo + hi)
+        x = jnp.where(grow, hi, jnp.where(search, mid, t))
+        cdf, pdf = cdf_pdf_vec(st, x, slot_kinds)
         below = cdf < q
-        lo = jnp.where(below, t, lo)
-        hi = jnp.where(below, hi, t)
-        step = t - (cdf - q) / jnp.where(pdf > 0.0, pdf, 1.0)
-        ok = (pdf > 0.0) & (step > lo) & (step < hi)
-        return lo, hi, jnp.where(ok, step, 0.5 * (lo + hi))
+        # grow: a below-q hi doubles, and becomes the bracket's known lower
+        # end (the scalar's free first bisection); bisect and Newton narrow
+        lo = jnp.where(below, x, lo)
+        hi = jnp.where(below, jnp.where(grow, 2.0 * hi, hi), jnp.where(grow, hi, x))
+        newton = x - (cdf - q) / jnp.where(pdf > 0.0, pdf, 1.0)
+        ok = (pdf > 0.0) & (newton > lo) & (newton < hi)
+        t = jnp.where(search | ~ok, 0.5 * (lo + hi), newton)
+        return lo, hi, t
 
-    lo, hi, t = jax.lax.fori_loop(0, EULER_NEWTON_ITERS, newton, (lo, hi, t))
+    lo, hi, t = jax.lax.fori_loop(
+        0, n_search + EULER_NEWTON_ITERS, step,
+        (jnp.zeros_like(hi0), hi0, hi0))
     return jnp.where(finite, jnp.clip(t, lo, hi), _INF)

@@ -32,7 +32,6 @@ from dataclasses import dataclass
 from functools import partial
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
@@ -42,6 +41,7 @@ from repro.core.scenario import (
     implied_service_var,
 )
 from repro.core.tail import resolve_tail_method
+from repro.jaxenv import x64
 
 from .batch import MODEL_CODES
 from .cluster import (
@@ -290,7 +290,7 @@ def solve_meanfield_equilibrium(
     if exo.shape != (e_n,):
         raise ScenarioError("exo_rates", f"expected shape ({e_n},), got {exo.shape}")
 
-    with jax.experimental.enable_x64():
+    with x64():
         cst_j = _as_jnp(cst)
         lam_j, bw_j, exo_j = jnp.asarray(lam_c), jnp.asarray(bw_c), jnp.asarray(exo)
         f = jnp.zeros((c_n, e_n + 1), dtype=jnp.float64).at[:, 0].set(1.0)
@@ -459,7 +459,7 @@ def simulate_meanfield(
     exo_true = traces.edge_bg_rate if traces.n_edges else \
         np.broadcast_to(cst["exo_rate"], (t_n, e_n)).copy()
 
-    with jax.experimental.enable_x64():
+    with x64():
         cst_j = _as_jnp(cst)
         f0 = jnp.zeros((spec.n_classes, e_n + 1), dtype=jnp.float64) \
             .at[:, 0].set(1.0)

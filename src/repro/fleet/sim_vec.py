@@ -29,11 +29,11 @@ from dataclasses import dataclass
 from functools import partial
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.simulation import steady_slice
+from repro.jaxenv import x64
 
 from .batch import ScenarioBatch
 
@@ -78,7 +78,7 @@ def lindley_station(arrivals, services, k=1, *, k_max: int | None = None):
             f"{k_needed}; the station would silently run with fewer servers")
     # float64 throughout: arrival clocks reach ~n/lam, and float32 ulps there
     # would swamp millisecond-scale waits
-    with jax.experimental.enable_x64():
+    with x64():
         arrivals = jnp.asarray(np.asarray(arrivals, dtype=np.float64))
         services = jnp.asarray(np.asarray(services, dtype=np.float64))
         k_arr = jnp.broadcast_to(jnp.asarray(k, dtype=jnp.int32), arrivals.shape[:1])
@@ -153,7 +153,7 @@ def simulate_fleet(
         raise ValueError(f"unknown strategy {strategy!r}")
     edge = None if m.group(1) is None else int(m.group(1))
 
-    with jax.experimental.enable_x64():
+    with x64():
         key = jax.random.PRNGKey(seed)
         keys = jax.random.split(key, 4)
         shape = (batch.size, n)

@@ -11,9 +11,9 @@ asymptote as the cheap method the closed-loop cluster paths use inside
 ``fleet_analytic`` is to ``Scenario.analytic()``, and a validation check pins
 the two to <= 1e-6 relative agreement over the full golden corpus.
 
-All math runs in float64 (complex128 contours) inside a scoped
-``jax.experimental.enable_x64()`` so the global f32 model/kernel stack is
-untouched. Algorithmic constants (Euler A/N/M, bracket/bisection iteration
+All math runs in float64 inside a scoped ``repro.jaxenv.x64()`` so the
+global f32 model/kernel stack is untouched; the Euler contour is carried as
+paired real/imaginary float64 arrays (no complex dtype reaches the compiler). Algorithmic constants (Euler A/N/M, bracket/bisection iteration
 counts) are imported from the scalar module — the agreement gate depends on
 both sides running the identical algorithm.
 
@@ -31,7 +31,6 @@ from dataclasses import dataclass
 from functools import partial
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,6 +44,7 @@ from repro.core.tail import (
     euler_grow_iters,
     resolve_tail_method,
 )
+from repro.jaxenv import x64
 
 from .analytic_vec import _implied_var_vec
 from .batch import ScenarioBatch
@@ -361,7 +361,7 @@ def fleet_tail(batch: ScenarioBatch, q: float, *, method: str = "euler") -> Flee
     proc_hint = None
     if not np.any(np.asarray(np_arrays["bg_lam"]) > 0.0):
         proc_hint = _uniform_kind_hint(np_arrays["edge_model"])
-    with jax.experimental.enable_x64():
+    with x64():
         arrays = {k: jnp.asarray(v) for k, v in np_arrays.items()}
         t_dev, t_edge, best = _fleet_tail_jit(arrays, jnp.float64(q),
                                               method=method,

@@ -7,15 +7,18 @@ target's CURRENT cost, and a cohort gate (client i re-decides only when
 ``t % stagger == i % stagger``). Sequential in the epoch axis (the previous
 choice is the carry), embarrassingly parallel in the client axis — the same
 shape as the Lindley kernel next door, so the same state-resident pattern
-applies: each grid cell keeps a (blk_n, 1) block of previous choices in
-VMEM scratch for the whole epoch sweep and streams (e1, blk_n, blk_t) cost
+applies: each grid cell keeps a (1, blk_n) row of previous choices in
+VMEM scratch for the whole epoch sweep and streams (blk_t, e1, blk_n) cost
 tiles through.
 
 Cost tables arrive time-major ``(T, N, E+1)`` (column 0 = on-device, the
-cluster convention) and are transposed to target-major ``(E+1, N, T)`` so
-the tiled axes are the client/epoch pair and the tiny target axis rides
-along whole. Epochs are innermost ("arbitrary") so the choice carry
-persists across t-blocks; the client axis is "parallel".
+cluster convention) and are laid out ``(T, E+1, N)``: clients on lanes, the
+tiny target axis on sublanes, epochs on the untiled leading axis, so each
+step reads one whole (E+1, blk_n) slab and writes one whole row of the
+``(T, N)`` output (a dynamic sublane index, which Mosaic accepts). On TPU
+``blk_n`` must be a multiple of 128 or the whole client axis. Epochs are
+innermost ("arbitrary") so the choice carry persists across t-blocks; the
+client axis is "parallel".
 """
 
 from __future__ import annotations
@@ -33,17 +36,16 @@ ON_DEVICE = -1  # target index convention (repro.core.manager.ON_DEVICE)
 
 
 def _compiler_params(grid_len: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
     sem = ("parallel",) * (grid_len - 1) + ("arbitrary",)
-    return cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def decision_scan_kernel(
     h_ref,  # (1, 1) SMEM — hysteresis fraction
-    costs_ref,  # (e1, blk_n, blk_t) stacked per-target costs, target-major
-    cohort_ref,  # (blk_n, 1) int32 — client's decision cohort
-    c_ref,  # (blk_n, blk_t) int32 choices out
-    prev_ref,  # scratch (blk_n, 1) int32 — previous choice per client row
+    costs_ref,  # (blk_t, e1, blk_n) stacked per-target costs
+    cohort_ref,  # (1, blk_n) int32 — client's decision cohort
+    c_ref,  # (blk_t, blk_n) int32 choices out
+    prev_ref,  # scratch (1, blk_n) int32 — previous choice per client
     *,
     blk_t: int,
     stagger: int,
@@ -54,21 +56,25 @@ def decision_scan_kernel(
     def _init():
         prev_ref[...] = jnp.full_like(prev_ref, ON_DEVICE)
 
-    e1, blk_n, _ = costs_ref.shape
+    e1 = costs_ref.shape[1]
     h = h_ref[0, 0]
-    cohort = cohort_ref[...]  # (blk_n, 1)
-    tgt_ids = jax.lax.broadcasted_iota(jnp.int32, (e1, blk_n, 1), 0)
+    cohort = cohort_ref[...]  # (1, blk_n)
 
     def step(t, prev):
         tg = it * blk_t + t  # global epoch index
-        costs_t = costs_ref[:, :, pl.dslice(t, 1)]  # (e1, blk_n, 1)
-        # first-argmin: ties go to the lowest target index, i.e. on-device
-        choice = jnp.argmin(costs_t, axis=0).astype(jnp.int32) - 1  # (blk_n, 1)
-        predicted = jnp.min(costs_t, axis=0)
-        # one-hot gather of the previous target's CURRENT cost (the masked
-        # where keeps +inf saturated columns from poisoning the sum)
-        prev_t = jnp.sum(
-            jnp.where(tgt_ids == prev[None, :, :] + 1, costs_t, 0.0), axis=0)
+        costs_t = costs_ref[t]  # (e1, blk_n)
+        # first-argmin over targets (a strict < keeps ties on the lowest
+        # index, i.e. on-device), gathering the previous target's CURRENT
+        # cost along the way
+        predicted = costs_t[0:1]
+        choice = jnp.full_like(prev, ON_DEVICE)
+        prev_t = jnp.where(prev == ON_DEVICE, predicted, 0.0)
+        for j in range(1, e1):
+            c_j = costs_t[j:j + 1]
+            better = c_j < predicted
+            predicted = jnp.where(better, c_j, predicted)
+            choice = jnp.where(better, j - 1, choice)
+            prev_t = jnp.where(prev == j - 1, c_j, prev_t)
         keep = (
             (tg >= stagger)
             & (h > 0.0)
@@ -78,7 +84,7 @@ def decision_scan_kernel(
         )
         decided = jnp.where(keep, prev, choice)
         new = jnp.where(cohort == tg % stagger, decided, prev).astype(jnp.int32)
-        c_ref[:, pl.dslice(t, 1)] = new
+        c_ref[pl.dslice(t, 1), :] = new
         return new
 
     prev_ref[...] = jax.lax.fori_loop(0, blk_t, step, prev_ref[...])
@@ -90,7 +96,7 @@ def decision_scan_pallas(
     *,
     hysteresis: float = 0.0,
     stagger: int = 1,
-    blk_n: int = 8,
+    blk_n: int = 128,
     blk_t: int = 128,
     interpret: bool = False,
 ):
@@ -100,14 +106,11 @@ def decision_scan_pallas(
     blk_t = min(blk_t, t)
     pad_n = (-n) % blk_n
     pad_t = (-t) % blk_t
-    cm = jnp.transpose(costs, (2, 1, 0))  # (e1, N, T) target-major
-    co = cohort.astype(jnp.int32)[:, None]  # (N, 1)
-    if pad_n or pad_t:
-        # padded epochs run after every real one and padded clients are
-        # whole extra rows — both are sliced off below, values irrelevant
-        cm = jnp.pad(cm, ((0, 0), (0, pad_n), (0, pad_t)))
-        co = jnp.pad(co, ((0, pad_n), (0, 0)))
-    _, np_, tp = cm.shape
+    # padded epochs run after every real one and padded clients are whole
+    # extra columns — both are sliced off below, values irrelevant
+    cm = jnp.pad(jnp.swapaxes(costs, 1, 2), ((0, pad_t), (0, 0), (0, pad_n)))
+    co = jnp.pad(cohort.astype(jnp.int32)[None, :], ((0, 0), (0, pad_n)))
+    tp, _, np_ = cm.shape
     grid = (np_ // blk_n, tp // blk_t)
     h = jnp.asarray(hysteresis, cm.dtype).reshape(1, 1)
     out = pl.pallas_call(
@@ -115,13 +118,13 @@ def decision_scan_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((e1, blk_n, blk_t), lambda i, it: (0, i, it)),
-            pl.BlockSpec((blk_n, 1), lambda i, it: (i, 0)),
+            pl.BlockSpec((blk_t, e1, blk_n), lambda i, it: (it, 0, i)),
+            pl.BlockSpec((1, blk_n), lambda i, it: (0, i)),
         ],
-        out_specs=pl.BlockSpec((blk_n, blk_t), lambda i, it: (i, it)),
-        out_shape=jax.ShapeDtypeStruct((np_, tp), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((blk_n, 1), jnp.int32)],
+        out_specs=pl.BlockSpec((blk_t, blk_n), lambda i, it: (it, i)),
+        out_shape=jax.ShapeDtypeStruct((tp, np_), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, blk_n), jnp.int32)],
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
     )(h, cm, co)
-    return out[:n, :t].T  # back to time-major (T, N)
+    return out[:t, :n]

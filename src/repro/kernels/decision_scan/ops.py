@@ -15,7 +15,7 @@ __all__ = ["decision_scan"]
 @partial(jax.jit,
          static_argnames=("impl", "hysteresis", "stagger", "blk_n", "blk_t"))
 def decision_scan(costs, cohort, *, hysteresis: float = 0.0, stagger: int = 1,
-                  impl: str = "pallas", blk_n: int = 8, blk_t: int = 128):
+                  impl: str = "pallas", blk_n: int = 128, blk_t: int = 128):
     if impl == "xla":
         return decision_scan_reference(
             costs, cohort, hysteresis=hysteresis, stagger=stagger)

@@ -26,9 +26,8 @@ NEG_INF = -2.0e38
 
 
 def _compiler_params(grid_len: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
     sem = ("parallel",) * (grid_len - 1) + ("arbitrary",)
-    return cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def flash_attention_kernel(

@@ -4,12 +4,16 @@ The fleet simulator's hot loop is the per-station recurrence
 ``dep_i = max(arr_i, dep_{i-1}) + svc_i`` — sequential in the job axis,
 embarrassingly parallel in the scenario axis. The XLA lowering of the
 equivalent ``lax.scan`` re-reads the carry from HBM every step; here each
-grid cell holds a (blk_b,) block of scenario clocks in registers/VMEM for the
-whole job sweep and streams the (blk_b, T) arrival/service tiles through —
+grid cell holds a (1, blk_b) row of scenario clocks in VMEM for the whole
+job sweep and streams the (blk_t, blk_b) arrival/service tiles through —
 the same state-resident pattern as the ssm_scan kernel next door.
 
-Time is innermost ("arbitrary") so the clock carry persists across t-blocks;
-the batch axis is "parallel".
+The kernel works time-major: jobs on sublanes, scenarios on lanes, so each
+step reads and writes one whole row (a dynamic sublane index, which Mosaic
+accepts) rather than one lane column (which it refuses unless the index is a
+provable multiple of 128). On TPU ``blk_b`` must be a multiple of 128 or the
+whole batch. Time is innermost ("arbitrary") so the clock carry persists
+across t-blocks; the batch axis is "parallel".
 """
 
 from __future__ import annotations
@@ -25,16 +29,15 @@ __all__ = ["lindley_scan_kernel", "lindley_scan_pallas"]
 
 
 def _compiler_params(grid_len: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
     sem = ("parallel",) * (grid_len - 1) + ("arbitrary",)
-    return cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def lindley_scan_kernel(
-    a_ref,  # (blk_b, blk_t) arrivals
-    s_ref,  # (blk_b, blk_t) services
-    d_ref,  # (blk_b, blk_t) departures out
-    clk_ref,  # scratch (blk_b, 1) f32 — last departure per scenario row
+    a_ref,  # (blk_t, blk_b) arrivals, time-major
+    s_ref,  # (blk_t, blk_b) services
+    d_ref,  # (blk_t, blk_b) departures out
+    clk_ref,  # scratch (1, blk_b) — last departure per scenario column
     *,
     blk_t: int,
 ):
@@ -45,21 +48,19 @@ def lindley_scan_kernel(
         clk_ref[...] = jnp.full_like(clk_ref, -jnp.inf)
 
     def step(t, clk):
-        a_t = a_ref[:, pl.dslice(t, 1)]  # (blk_b, 1)
-        s_t = s_ref[:, pl.dslice(t, 1)]
-        dep = jnp.maximum(a_t, clk) + s_t
-        d_ref[:, pl.dslice(t, 1)] = dep.astype(d_ref.dtype)
+        row = pl.dslice(t, 1)
+        dep = jnp.maximum(a_ref[row, :], clk) + s_ref[row, :]  # (1, blk_b)
+        d_ref[row, :] = dep.astype(d_ref.dtype)
         return dep
 
-    clk = jax.lax.fori_loop(0, blk_t, step, clk_ref[...])
-    clk_ref[...] = clk
+    clk_ref[...] = jax.lax.fori_loop(0, blk_t, step, clk_ref[...])
 
 
 def lindley_scan_pallas(
     arrivals: jax.Array,  # (B, T), non-decreasing along T per row
     services: jax.Array,  # (B, T)
     *,
-    blk_b: int = 8,
+    blk_b: int = 128,
     blk_t: int = 512,
     interpret: bool = False,
 ):
@@ -69,24 +70,21 @@ def lindley_scan_pallas(
     blk_t = min(blk_t, t)
     pad_b = (-b) % blk_b
     pad_t = (-t) % blk_t
-    if pad_b or pad_t:
-        # padded jobs arrive at +0 service after the real ones; their rows /
-        # tail columns are sliced off below, so values are irrelevant
-        arrivals = jnp.pad(arrivals, ((0, pad_b), (0, pad_t)))
-        services = jnp.pad(services, ((0, pad_b), (0, pad_t)))
-    bp, tp = arrivals.shape
+    # padded jobs arrive at +0 service after the real ones; the padded job
+    # rows and scenario columns are sliced off below, so values are irrelevant
+    at = jnp.pad(arrivals.T, ((0, pad_t), (0, pad_b)))
+    st = jnp.pad(services.T, ((0, pad_t), (0, pad_b)))
+    tp, bp = at.shape
     grid = (bp // blk_b, tp // blk_t)
+    tile = pl.BlockSpec((blk_t, blk_b), lambda ib, it: (it, ib))
     out = pl.pallas_call(
         functools.partial(lindley_scan_kernel, blk_t=blk_t),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk_b, blk_t), lambda ib, it: (ib, it)),
-            pl.BlockSpec((blk_b, blk_t), lambda ib, it: (ib, it)),
-        ],
-        out_specs=pl.BlockSpec((blk_b, blk_t), lambda ib, it: (ib, it)),
-        out_shape=jax.ShapeDtypeStruct((bp, tp), arrivals.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_b, 1), arrivals.dtype)],
+        in_specs=[tile, tile],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((tp, bp), arrivals.dtype),
+        scratch_shapes=[pltpu.VMEM((1, blk_b), arrivals.dtype)],
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
-    )(arrivals, services)
-    return out[:b, :t]
+    )(at, st)
+    return out[:t, :b].T

@@ -13,7 +13,7 @@ __all__ = ["lindley_scan"]
 
 
 @partial(jax.jit, static_argnames=("impl", "blk_b", "blk_t"))
-def lindley_scan(arrivals, services, *, impl: str = "pallas", blk_b: int = 8, blk_t: int = 512):
+def lindley_scan(arrivals, services, *, impl: str = "pallas", blk_b: int = 128, blk_t: int = 512):
     if impl == "xla":
         return lindley_scan_reference(arrivals, services)
     return lindley_scan_pallas(

@@ -18,8 +18,7 @@ __all__ = ["rmsnorm_kernel", "rmsnorm_pallas"]
 
 
 def _compiler_params(grid_len: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel",) * grid_len)
+    return pltpu.CompilerParams(dimension_semantics=("parallel",) * grid_len)
 
 
 def rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):

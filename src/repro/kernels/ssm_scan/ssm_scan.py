@@ -22,9 +22,8 @@ __all__ = ["ssm_scan_kernel", "ssm_scan_pallas"]
 
 
 def _compiler_params(grid_len: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
     sem = ("parallel",) * (grid_len - 1) + ("arbitrary",)
-    return cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def ssm_scan_kernel(
@@ -74,7 +73,9 @@ def ssm_scan_pallas(
 ):
     """Returns y (B, T, D) (final state is recovered by the wrapper when
     needed via a short reference tail — the kernel's contract is the output
-    sequence, matching the training hot path)."""
+    sequence, matching the training hot path). On TPU ``blk_d`` must be a
+    multiple of 128 or all of D, and ``blk_t`` a multiple of 8 or all of T
+    (the block tiling rule)."""
     B, T, D = u.shape
     N = A.shape[1]
     blk_t = min(blk_t, T)

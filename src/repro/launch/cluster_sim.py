@@ -61,6 +61,7 @@ from repro.fleet import (
     static_fractions,
     step_signal,
 )
+from repro.jaxenv import enable_compilation_cache
 
 __all__ = [
     "TraceSpecError",
@@ -582,4 +583,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

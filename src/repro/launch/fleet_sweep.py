@@ -25,6 +25,7 @@ import numpy as np
 from repro.core.latency import NetworkPath, Tier, Workload
 from repro.core.scenario import EdgeSpec, Scenario
 from repro.fleet import ScenarioBatch, fleet_analytic, fleet_crossover
+from repro.jaxenv import enable_compilation_cache
 from repro.obs import run_manifest
 
 __all__ = ["default_scenario", "parse_axis", "run_sweep", "main"]
@@ -166,4 +167,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

@@ -24,6 +24,7 @@ import json
 import time
 from pathlib import Path
 
+from repro.jaxenv import enable_compilation_cache
 from repro.measure import (
     HarnessConfig,
     MeasuredTrace,
@@ -206,4 +207,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

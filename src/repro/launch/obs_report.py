@@ -31,6 +31,7 @@ import argparse
 import json
 from pathlib import Path
 
+from repro.jaxenv import enable_compilation_cache
 from repro.obs import (
     AuditLog,
     MetricsRegistry,
@@ -162,4 +163,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

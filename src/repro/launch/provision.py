@@ -22,6 +22,7 @@ from pathlib import Path
 
 from repro.core.latency import NetworkPath, Tier, Workload
 from repro.core.scenario import EdgeSpec, Scenario
+from repro.jaxenv import enable_compilation_cache
 from repro.plan import ProvisionSpace, provision
 
 __all__ = ["default_space", "main"]
@@ -159,4 +160,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

@@ -25,6 +25,7 @@ from pathlib import Path
 
 from repro.exp.runner import diff_results, run_experiment
 from repro.exp.spec import ExperimentError, registry
+from repro.jaxenv import enable_compilation_cache
 from repro.obs import run_manifest
 
 __all__ = ["main"]
@@ -273,4 +274,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

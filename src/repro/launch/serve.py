@@ -1,8 +1,9 @@
 """Serving launcher CLI: engine + Poisson workload + Algorithm-1 gateway.
 
-Serves a (reduced, CPU-runnable) model through the slot-based engine while
-the offload gateway replays a bandwidth schedule and reports its decisions —
-the deployable shape of the paper's resource manager.
+Serves a model through the slot-based engine while the offload gateway
+replays a bandwidth schedule and reports its decisions — the deployable shape
+of the paper's resource manager. The model is the reduced CPU-runnable proxy
+of the architecture unless ``--full-config`` asks for its published widths.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch starcoder2_3b \
@@ -18,7 +19,9 @@ import numpy as np
 
 from repro.configs.base import ARCH_IDS, get_config
 from repro.core.latency import ServiceModel, Tier, Workload
+from repro.jaxenv import enable_compilation_cache
 from repro.models import lm
+from repro.models.params import tree_bytes
 from repro.obs import AuditLog, MetricsRegistry, format_decision
 from repro.serving.engine import Engine, ServeConfig
 from repro.serving.gateway import EdgeHandle, OffloadGateway
@@ -38,10 +41,17 @@ def main(argv=None) -> int:
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--schedule", type=str, default="20,10,2,20",
                     help="bandwidth schedule in Mbps, one epoch each")
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the published-width config (default: reduced CPU proxy)")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch).reduced(seq_chunk=8)
+    cfg = get_config(args.arch)
+    if not args.full_config:
+        cfg = cfg.reduced(seq_chunk=8)
     params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    print(f"[serve] {cfg.name}: d_model={cfg.d_model} "
+          f"layers={cfg.num_superblocks * len(cfg.superblock)} dtype={cfg.dtype} "
+          f"params={tree_bytes(params)} bytes on {jax.devices()[0].device_kind}")
     engine = Engine(cfg, params, ServeConfig(slots=args.slots, max_seq=64))
 
     # warmup first so JIT compilation never pollutes the profiled service
@@ -85,8 +95,10 @@ def main(argv=None) -> int:
     print(f"[gateway] switches={gw.switches}")
     for line in metrics.render().splitlines():
         print(f"[metrics] {line}")
-    return 0
+    # every submitted request must come back answered
+    return 0 if len(engine.completed) == args.requests else 1
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

@@ -30,6 +30,7 @@ import json
 from pathlib import Path
 
 from repro.exp.payloads import run_validate
+from repro.jaxenv import enable_compilation_cache
 from repro.validate import (
     DEFAULT_MAPE_BUDGET_PCT,
     DEFAULT_SEED,
@@ -164,4 +165,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     raise SystemExit(main())

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any
 
 import jax
@@ -66,6 +67,17 @@ def _path_key(path) -> int:
     return int.from_bytes(hashlib.sha256(s.encode()).digest()[:4], "little")
 
 
+@partial(jax.jit, static_argnames=("shape", "dtype"))
+def _draw_normal(key, std, shape, dtype):
+    """``(normal(key, shape, f32) * std).astype(dtype)`` as one program: the
+    scaled float32 copy never lands on the device next to the resident
+    leaves, which a published-width MLP stack (1.1 G elements) cannot afford.
+    The barrier keeps the draw unfused, so the values are bit-identical to
+    the eager expression."""
+    z = jax.lax.optimization_barrier(jax.random.normal(key, shape, jnp.float32))
+    return (z * std).astype(dtype)
+
+
 def init_params(template: Any, key: jax.Array, dtype: jnp.dtype) -> Any:
     """Materialise arrays. Per-leaf keys are fold_in(key, hash(path)):
     deterministic, order-independent, stable across refactors."""
@@ -79,10 +91,9 @@ def init_params(template: Any, key: jax.Array, dtype: jnp.dtype) -> Any:
             return jnp.ones(leaf.shape, d)
         if leaf.init == "fan_in":
             fan_in = leaf.shape[-2] if len(leaf.shape) >= 2 else leaf.shape[-1]
-            std = 1.0 / np.sqrt(fan_in)
-            return (jax.random.normal(k, leaf.shape, jnp.float32) * std).astype(d)
+            return _draw_normal(k, 1.0 / np.sqrt(fan_in), leaf.shape, d)
         if leaf.init == "normal":
-            return (jax.random.normal(k, leaf.shape, jnp.float32) * leaf.std).astype(d)
+            return _draw_normal(k, leaf.std, leaf.shape, d)
         raise ValueError(leaf.init)
 
     return jax.tree_util.tree_map_with_path(f, template, is_leaf=_is_tspec)

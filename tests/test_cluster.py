@@ -482,6 +482,7 @@ class TestShardedScan:
             "print('SHARDMAP_OK')\n"
         )
         env = dict(os.environ,
+                   JAX_PLATFORMS="cpu",
                    XLA_FLAGS="--xla_force_host_platform_device_count=4",
                    PYTHONPATH=src)
         proc = subprocess.run([sys.executable, "-c", script], env=env,

@@ -194,7 +194,8 @@ class TestSweepErgonomics:
 
 
 class TestAnalyticVecCoherence:
-    @settings(max_examples=25)
+    # deadline=None: the first example pays the jit compile
+    @settings(max_examples=25, deadline=None)
     @given(_point)
     def test_matches_scalar_analytic(self, p):
         lam, s_dev, s_edge, k_edge, mbps, m_dev, m_edge, n_bg = p

@@ -135,6 +135,22 @@ class TestSsmScan:
         np.testing.assert_allclose(y_one, y_many, rtol=1e-5, atol=1e-5)
 
 
+class TestLindleyScan:
+    @pytest.mark.parametrize("B,T,bb,bt", [(16, 1024, 16, 256),  # bench shape
+                                           (5, 37, 8, 16),  # pads both axes
+                                           (3, 64, 3, 8)])  # many t-blocks
+    def test_against_reference(self, B, T, bb, bt):
+        """The time-major kernel, its padding and its clock carry across
+        t-blocks reproduce the lax.scan recursion exactly."""
+        from repro.kernels.lindley_scan.ops import lindley_scan
+
+        rng = np.random.default_rng(B * T)
+        arr = jnp.asarray(np.cumsum(rng.exponential(0.1, (B, T)), axis=1), jnp.float32)
+        svc = jnp.asarray(rng.exponential(0.08, (B, T)), jnp.float32)
+        out = lindley_scan(arr, svc, impl="interpret", blk_b=bb, blk_t=bt)
+        assert jnp.array_equal(out, lindley_scan(arr, svc, impl="xla"))
+
+
 class TestDecisionScan:
     @staticmethod
     def _costs(T, N, E1, seed=4):
@@ -176,13 +192,12 @@ class TestDecisionScan:
     def test_reference_matches_cluster_decide_rule(self):
         """The oracle is pinned to the production decision rule: iterate
         ``repro.fleet.cluster._decide_vec`` by hand over the same tables."""
-        import jax.experimental
-
         from repro.fleet.cluster import _decide_vec
+        from repro.jaxenv import x64
         from repro.kernels.decision_scan.ref import decision_scan_reference
 
         T, N = 25, 6
-        with jax.experimental.enable_x64():
+        with x64():
             costs = jnp.asarray(np.asarray(self._costs(T, N, 4)), jnp.float64)
             h, prev, manual = 0.15, jnp.full(N, -1, jnp.int32), []
             for t in range(T):

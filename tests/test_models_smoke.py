@@ -84,3 +84,22 @@ def test_param_counts_match_full_configs():
     for arch, (lo, hi) in expect.items():
         n = lm.num_params(get_config(arch))
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B params outside [{lo/1e9}, {hi/1e9}]"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_param_draw_matches_the_eager_expression(dtype):
+    """init_params draws each leaf in one jitted program; its values must be
+    the eager ``(normal * std).astype(dtype)`` bit for bit, so seeded runs
+    keep their weights."""
+    import numpy as np
+
+    from repro.models.params import TSpec, _path_key, init_params
+
+    tpl = {"w": TSpec((3, 64, 96), (None, None, None), init="fan_in"),
+           "e": TSpec((257, 64), (None, None), std=0.02)}
+    got = init_params(tpl, KEY, jnp.dtype(dtype))
+    for path, std in ((("w",), 1.0 / np.sqrt(64)), (("e",), 0.02)):
+        leaf = tpl[path[0]]
+        k = jax.random.fold_in(KEY, _path_key((jax.tree_util.DictKey(path[0]),)))
+        want = (jax.random.normal(k, leaf.shape, jnp.float32) * std).astype(dtype)
+        assert np.array_equal(np.asarray(got[path[0]]), np.asarray(want))

@@ -10,7 +10,6 @@ whether the spec is later consumed by the scalar or the vectorized engine.
 
 import math
 
-import jax.experimental
 import numpy as np
 import pytest
 
@@ -25,6 +24,7 @@ from repro.fleet.analytic_vec import (
     mm1_wait_vec,
     mmk_wait_erlang_vec,
 )
+from repro.jaxenv import x64
 
 MU = 10.0
 # rho ladder approaching 1 from below; float64 still resolves mu - lam here
@@ -52,7 +52,7 @@ class TestBlowupFiniteAndMonotone:
         # the vec primitives are documented to run inside a scoped x64
         # context (fleet_analytic provides it); replicate that here
         lam = RHOS * MU
-        with jax.experimental.enable_x64():
+        with x64():
             waits = [np.asarray(w) for w in (
                 mm1_wait_vec(lam, MU), md1_wait_vec(lam, MU),
                 mg1_wait_vec(lam, MU, 0.02))]
@@ -71,7 +71,7 @@ class TestBlowupFiniteAndMonotone:
     def test_scalar_and_vectorized_blowups_match_pointwise(self):
         lam = RHOS * MU
         scalar = np.array([Q.mm1_wait(la, MU) for la in lam])
-        with jax.experimental.enable_x64():
+        with x64():
             vec = np.asarray(mm1_wait_vec(lam, MU))
         np.testing.assert_allclose(vec, scalar, rtol=1e-12)
 
@@ -85,14 +85,14 @@ class TestAtAndPastSaturation:
         assert Q.mg1_wait(lam, MU, 0.02) == math.inf
         assert Q.mmk_wait_erlang(lam * 4, MU, 4) == math.inf  # lam >= k*mu
         assert np.asarray(L.mm1_wait(lam, MU)) == np.inf
-        with jax.experimental.enable_x64():
+        with x64():
             assert np.asarray(mm1_wait_vec(np.array([lam]), MU))[0] == np.inf
             assert np.asarray(md1_wait_vec(np.array([lam]), MU))[0] == np.inf
             assert np.asarray(mg1_wait_vec(np.array([lam]), MU, 0.02))[0] == np.inf
 
     def test_negative_arrival_is_inf_not_negative_wait(self):
         assert Q.mm1_wait(-1.0, MU) == math.inf
-        with jax.experimental.enable_x64():
+        with x64():
             assert np.asarray(mm1_wait_vec(np.array([-1.0]), MU))[0] == np.inf
 
 
