@@ -22,6 +22,22 @@ measurement-grade (repro.measure relies on it):
     deterministic service-time model for the wall clock (the "simulated
     clock" mode of ``repro.measure.harness``) while the engine still runs the
     real model for token-level correctness.
+
+The served loop's phases are ``jax.profiler.TraceAnnotation`` spans, so a
+profile of a served run shows them beside the device's trace (always on;
+without a profiler session each costs under a microsecond):
+
+  * ``engine.admit`` — one admitted request, from popping it off the queue
+    to setting its slot state;
+  * ``engine.decode`` — one decode step, from building its inputs to the
+    end of the per-slot bookkeeping;
+  * inside either, in order: ``engine.launch`` (host-to-device uploads and
+    the dispatch of the jitted call, plus the eager slot write on
+    admission), ``engine.wait`` (``block_until_ready``) and
+    ``engine.sample`` (``argmax`` and its copy to the host).
+
+The jitted programs are named ``engine_prefill`` and ``engine_decode``
+(``jit_engine_prefill(...)`` and ``jit_engine_decode(...)`` in a profile).
 """
 
 from __future__ import annotations
@@ -33,6 +49,7 @@ from typing import Any, Callable, Iterable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
@@ -91,6 +108,13 @@ class ServiceEvent(NamedTuple):
 Timer = Callable[..., tuple[Any, float]]
 
 
+def _stamp(now: float | None, charged: float = 0.0) -> float:
+    """An event's time: the wall clock as it happens when the engine owns
+    the clock (``now is None``), else the caller's ``now`` plus the service
+    charged before the event."""
+    return time.time() if now is None else now + charged
+
+
 class Engine:
     """Single-model serving engine over the lm prefill/decode steps.
 
@@ -111,12 +135,15 @@ class Engine:
         # tracer=None and Tracer(enabled=False) cost exactly one bool test.
         self.tracer = tracer
         self._trace = tracer is not None and getattr(tracer, "enabled", True)
-        self._decode = jax.jit(
-            lambda p, tok, pos, caches: lm.decode_step(p, cfg, tok, pos, caches)
-        )
-        self._prefill = jax.jit(
-            lambda p, tokens: lm.prefill(p, cfg, tokens)
-        )
+
+        def engine_decode(p, tok, pos, caches):
+            return lm.decode_step(p, cfg, tok, pos, caches)
+
+        def engine_prefill(p, tokens):
+            return lm.prefill(p, cfg, tokens)
+
+        self._decode = jax.jit(engine_decode)
+        self._prefill = jax.jit(engine_prefill)
         # slot state
         B, S = sc.slots, sc.max_seq
         self.caches = self._zero_caches(B, S)
@@ -148,7 +175,10 @@ class Engine:
             out, dt = self.timer(phase, run, tokens=tokens, occupancy=occupancy)
             return out, float(dt)
         t0 = time.perf_counter()
-        out = jax.block_until_ready(run())
+        with _span("engine.launch"):
+            out = run()
+        with _span("engine.wait"):
+            jax.block_until_ready(out)
         return out, time.perf_counter() - t0
 
     def warmup(self, prompt_lens: Iterable[int] = (), *, decode: bool = True) -> None:
@@ -175,62 +205,72 @@ class Engine:
     def submit(self, req: Request) -> None:
         self.queue.append(req)
 
-    def _admit(self, now: float) -> float:
+    def _admit(self, now: float | None) -> float | None:
         """Admit queued requests into free slots; returns the advanced clock
-        (each prefill occupies the accelerator, so admissions serialise)."""
+        (each prefill occupies the accelerator, so admissions serialise).
+        ``now`` is ``None`` when the engine stamps events with the wall clock
+        as they happen (see :meth:`tick`)."""
         for slot in range(self.sc.slots):
             if self.active[slot] is not None or not self.queue:
                 continue
-            req = self.queue.pop(0)
-            L = len(req.prompt)
-            cold = self.timer is None and L not in self._warm_prefill
-
-            def run():
-                prompt = jnp.asarray(req.prompt[None], jnp.int32)
-                logits, caches = self._prefill(self.params, prompt)
-                # write this request's cache into the slot (batch index
-                # `slot`) inside the timed region — the copy is device work
-                # the request's service genuinely includes
-                new = jax.tree.map(
-                    lambda full, one: self._write_slot(full, one, slot, L),
-                    self.caches,
-                    caches,
-                )
-                return logits, new
-
-            req.t_admit = now
-            (logits, new_caches), dt = self._timed(
-                "prefill", run, tokens=L, occupancy=1)
-            self.caches = new_caches
-            self._warm_prefill.add(L)
-            next_tok = int(jnp.argmax(logits[0, -1]))
-            self.positions[slot] = L
-            self.remaining[slot] = req.max_new_tokens - 1
-            req.tokens_out.append(next_tok)
-            req.t_first_token = now + dt
-            self.service_log.append(
-                ServiceEvent(now, "prefill", dt, 1, req.rid, L, cold))
-            if self._trace:
-                track = f"req[{req.rid}]"
-                self.tracer.span(
-                    t=req.arrival_s, dur=max(0.0, now - req.arrival_s),
-                    name="queue", cat="queue", track=track, rid=req.rid)
-                self.tracer.span(
-                    t=now, dur=dt, name="prefill", cat="prefill", track=track,
-                    rid=req.rid, tokens=L, compile=cold)
-            now += dt
-            if self.remaining[slot] <= 0:
-                # single-token request: prefill IS the whole service
-                req.t_done = req.t_first_token
-                self.completed.append(req)
-                if self._trace:
-                    self.tracer.instant(
-                        t=req.t_done, name="respond", cat="respond",
-                        track=f"req[{req.rid}]", rid=req.rid,
-                        tokens=len(req.tokens_out), latency_s=req.latency_s)
-            else:
-                self.active[slot] = req
+            with _span("engine.admit"):
+                dt = self._admit_one(self.queue.pop(0), slot, now)
+            if now is not None:
+                now += dt
         return now
+
+    def _admit_one(self, req: Request, slot: int, now: float | None) -> float:
+        """Prefill ``req`` into ``slot``; returns its service seconds."""
+        L = len(req.prompt)
+        cold = self.timer is None and L not in self._warm_prefill
+
+        def run():
+            prompt = jnp.asarray(req.prompt[None], jnp.int32)
+            logits, caches = self._prefill(self.params, prompt)
+            # write this request's cache into the slot (batch index
+            # `slot`) inside the timed region — the copy is device work
+            # the request's service genuinely includes
+            new = jax.tree.map(
+                lambda full, one: self._write_slot(full, one, slot, L),
+                self.caches,
+                caches,
+            )
+            return logits, new
+
+        start = _stamp(now)
+        req.t_admit = start
+        (logits, new_caches), dt = self._timed(
+            "prefill", run, tokens=L, occupancy=1)
+        self.caches = new_caches
+        self._warm_prefill.add(L)
+        with _span("engine.sample"):
+            next_tok = int(jnp.argmax(logits[0, -1]))
+        self.positions[slot] = L
+        self.remaining[slot] = req.max_new_tokens - 1
+        req.tokens_out.append(next_tok)
+        req.t_first_token = _stamp(now, dt)
+        self.service_log.append(
+            ServiceEvent(start, "prefill", dt, 1, req.rid, L, cold))
+        if self._trace:
+            track = f"req[{req.rid}]"
+            self.tracer.span(
+                t=req.arrival_s, dur=max(0.0, start - req.arrival_s),
+                name="queue", cat="queue", track=track, rid=req.rid)
+            self.tracer.span(
+                t=start, dur=dt, name="prefill", cat="prefill", track=track,
+                rid=req.rid, tokens=L, compile=cold)
+        if self.remaining[slot] <= 0:
+            # single-token request: prefill IS the whole service
+            req.t_done = req.t_first_token
+            self.completed.append(req)
+            if self._trace:
+                self.tracer.instant(
+                    t=req.t_done, name="respond", cat="respond",
+                    track=f"req[{req.rid}]", rid=req.rid,
+                    tokens=len(req.tokens_out), latency_s=req.latency_s)
+        else:
+            self.active[slot] = req
+        return dt
 
     @staticmethod
     def _write_slot(full, one, slot: int, prompt_len: int):
@@ -248,51 +288,62 @@ class Engine:
     def tick(self, now: float | None = None) -> int:
         """Admit + one decode step for all active slots. Returns #active.
 
-        ``now`` is the engine clock at tick start (wall time when omitted);
-        completion stamps land at ``now + elapsed service``, so request
-        timestamps are event times, not tick-start times.
+        ``now`` is the caller's engine clock at tick start; events then land
+        at ``now`` plus the service charged before them, so request
+        timestamps are event times, not tick-start times. When ``now`` is
+        omitted and no ``timer`` is set, the engine stamps each event
+        (service starts, first tokens, completions, ``repro.obs`` spans)
+        with ``time.time()`` at the moment it happens: the profiler's host
+        clock, host time between calls included.
         """
-        now = time.time() if now is None else now
+        if now is None and self.timer is not None:
+            now = time.time()
         now = self._admit(now)
         if not any(r is not None for r in self.active):
             return 0
         cold = self.timer is None and not self._warm_decode
 
-        last = np.zeros((self.sc.slots, 1), np.int32)
-        for slot, req in enumerate(self.active):
-            if req is not None:
-                last[slot, 0] = req.tokens_out[-1]
-        pos = int(max(self.positions[s] for s, r in enumerate(self.active) if r is not None))
-        n_active = sum(r is not None for r in self.active)
+        with _span("engine.decode"):
+            last = np.zeros((self.sc.slots, 1), np.int32)
+            for slot, req in enumerate(self.active):
+                if req is not None:
+                    last[slot, 0] = req.tokens_out[-1]
+            pos = int(max(self.positions[s] for s, r in enumerate(self.active)
+                          if r is not None))
+            n_active = sum(r is not None for r in self.active)
 
-        def run():
-            return self._decode(self.params, jnp.asarray(last), jnp.int32(pos), self.caches)
+            def run():
+                return self._decode(self.params, jnp.asarray(last), jnp.int32(pos),
+                                    self.caches)
 
-        (logits, new_caches), dt = self._timed(
-            "decode", run, tokens=n_active, occupancy=n_active)
-        self.caches = new_caches
-        self._warm_decode = True
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            req.tokens_out.append(int(nxt[slot]))
-            self.positions[slot] += 1
-            self.remaining[slot] -= 1
-            if self.remaining[slot] <= 0 or self.positions[slot] >= self.sc.max_seq - 1:
-                req.t_done = now + dt
-                self.completed.append(req)
-                self.active[slot] = None
-                if self._trace:
-                    self.tracer.instant(
-                        t=req.t_done, name="respond", cat="respond",
-                        track=f"req[{req.rid}]", rid=req.rid,
-                        tokens=len(req.tokens_out), latency_s=req.latency_s)
+            start = _stamp(now)
+            (logits, new_caches), dt = self._timed(
+                "decode", run, tokens=n_active, occupancy=n_active)
+            self.caches = new_caches
+            self._warm_decode = True
+            with _span("engine.sample"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            end = _stamp(now, dt)
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                req.tokens_out.append(int(nxt[slot]))
+                self.positions[slot] += 1
+                self.remaining[slot] -= 1
+                if self.remaining[slot] <= 0 or self.positions[slot] >= self.sc.max_seq - 1:
+                    req.t_done = end
+                    self.completed.append(req)
+                    self.active[slot] = None
+                    if self._trace:
+                        self.tracer.instant(
+                            t=req.t_done, name="respond", cat="respond",
+                            track=f"req[{req.rid}]", rid=req.rid,
+                            tokens=len(req.tokens_out), latency_s=req.latency_s)
         self.service_log.append(
-            ServiceEvent(now, "decode", dt, n_active, -1, n_active, cold))
+            ServiceEvent(start, "decode", dt, n_active, -1, n_active, cold))
         if self._trace:
             self.tracer.span(
-                t=now, dur=dt, name="decode", cat="decode", track="engine",
+                t=start, dur=dt, name="decode", cat="decode", track="engine",
                 occupancy=n_active, compile=cold)
         return n_active
 
