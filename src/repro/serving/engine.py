@@ -34,10 +34,14 @@ without a profiler session each costs under a microsecond):
   * inside either, in order: ``engine.launch`` (host-to-device uploads and
     the dispatch of the jitted call, plus the eager slot write on
     admission), ``engine.wait`` (``block_until_ready``) and
-    ``engine.sample`` (``argmax`` and its copy to the host).
+    ``engine.sample`` (the host copy of the token ids the program picked,
+    and their conversion to ``int``; no device op runs in it).
 
 The jitted programs are named ``engine_prefill`` and ``engine_decode``
 (``jit_engine_prefill(...)`` and ``jit_engine_decode(...)`` in a profile).
+Each picks the greedy next token itself: it returns the ``argmax`` of its
+logits as int32 ids, never the logits, so sampling dispatches nothing on
+the host.
 """
 
 from __future__ import annotations
@@ -136,11 +140,16 @@ class Engine:
         self.tracer = tracer
         self._trace = tracer is not None and getattr(tracer, "enabled", True)
 
+        # lm.decode_step returns (logits (slots, 1, V), new caches) and
+        # lm.prefill (logits (1, 1, V) of the last position, caches); each
+        # program keeps only the greedy ids: (slots,) and (1,) int32.
         def engine_decode(p, tok, pos, caches):
-            return lm.decode_step(p, cfg, tok, pos, caches)
+            logits, caches = lm.decode_step(p, cfg, tok, pos, caches)
+            return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), caches
 
         def engine_prefill(p, tokens):
-            return lm.prefill(p, cfg, tokens)
+            logits, caches = lm.prefill(p, cfg, tokens)
+            return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), caches
 
         self._decode = jax.jit(engine_decode)
         self._prefill = jax.jit(engine_prefill)
@@ -226,7 +235,7 @@ class Engine:
 
         def run():
             prompt = jnp.asarray(req.prompt[None], jnp.int32)
-            logits, caches = self._prefill(self.params, prompt)
+            first, caches = self._prefill(self.params, prompt)
             # write this request's cache into the slot (batch index
             # `slot`) inside the timed region — the copy is device work
             # the request's service genuinely includes
@@ -235,16 +244,16 @@ class Engine:
                 self.caches,
                 caches,
             )
-            return logits, new
+            return first, new
 
         start = _stamp(now)
         req.t_admit = start
-        (logits, new_caches), dt = self._timed(
+        (first, new_caches), dt = self._timed(
             "prefill", run, tokens=L, occupancy=1)
         self.caches = new_caches
         self._warm_prefill.add(L)
         with _span("engine.sample"):
-            next_tok = int(jnp.argmax(logits[0, -1]))
+            next_tok = int(np.asarray(first)[0])
         self.positions[slot] = L
         self.remaining[slot] = req.max_new_tokens - 1
         req.tokens_out.append(next_tok)
@@ -317,17 +326,17 @@ class Engine:
                                     self.caches)
 
             start = _stamp(now)
-            (logits, new_caches), dt = self._timed(
+            (next_ids, new_caches), dt = self._timed(
                 "decode", run, tokens=n_active, occupancy=n_active)
             self.caches = new_caches
             self._warm_decode = True
             with _span("engine.sample"):
-                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                nxt = np.asarray(next_ids).tolist()
             end = _stamp(now, dt)
             for slot, req in enumerate(self.active):
                 if req is None:
                     continue
-                req.tokens_out.append(int(nxt[slot]))
+                req.tokens_out.append(nxt[slot])
                 self.positions[slot] += 1
                 self.remaining[slot] -= 1
                 if self.remaining[slot] <= 0 or self.positions[slot] >= self.sc.max_seq - 1:

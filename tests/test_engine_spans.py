@@ -1,7 +1,8 @@
 """The engine's phase spans in a CPU profile of a tiny served run: the five
 names, their nesting, one span per logged service event, the programs'
-stable names, wall-clock stamps on the profiler's clock, and tokens that do
-not depend on whether a profiler session is on."""
+stable names, no program dispatched while sampling, wall-clock stamps on
+the profiler's clock, and tokens that do not depend on whether a profiler
+session is on."""
 
 from __future__ import annotations
 
@@ -127,6 +128,18 @@ def test_programs_have_stable_names(profiled):
     assert not any("lambda" in n for n in called)
     text = eng._prefill.lower(eng.params, jnp.zeros((1, 8), jnp.int32)).as_text()
     assert "@jit_engine_prefill" in text.splitlines()[0]
+
+
+def test_programs_pick_the_tokens(profiled):
+    """No jitted call starts inside any ``engine.sample`` span: the prefill
+    and decode programs return the greedy ids, and sampling only copies
+    them to the host, on every admission and every decode step."""
+    _, _, events, _ = profiled
+    samples = _named(events, "engine.sample")
+    assert len(samples) == 3 + 5
+    calls = [s for n, s, _ in events if n.startswith("PjitFunction(")]
+    assert calls
+    assert [c for c in calls if any(s <= c < e for s, e in samples)] == []
 
 
 def _phases(events, parent):

@@ -39,25 +39,35 @@ class TestEngine:
             assert len(r.tokens_out) == r.max_new_tokens
             assert all(0 <= t < cfg.padded_vocab for t in r.tokens_out)
 
-    def test_greedy_decode_matches_reference(self, engine):
+    @pytest.mark.parametrize("slots", [1, 2])
+    def test_greedy_decode_matches_reference(self, engine, slots):
         """The engine's slot-cache path must reproduce a straight greedy
-        decode of the same prompt."""
+        decode of the same prompt. With two slots, two prompts of the same
+        length decode together, and each request must get the ids its own
+        slot's logits pick."""
         cfg, _ = engine
         params = lm.init_model(cfg, KEY)
-        eng = Engine(cfg, params, ServeConfig(slots=1, max_seq=64))
-        prompt = np.arange(1, 9, dtype=np.int32)
-        req = Request(rid=0, prompt=prompt, max_new_tokens=4)
-        eng.submit(req)
+        eng = Engine(cfg, params, ServeConfig(slots=slots, max_seq=64))
+        prompts = [np.arange(1, 9, dtype=np.int32),
+                   np.arange(200, 120, -10, dtype=np.int32)][:slots]
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=4) for i, p in enumerate(prompts)]
+        for req in reqs:
+            eng.submit(req)
         eng.drain()
-        # reference greedy
-        seq = jnp.asarray(prompt[None], jnp.int32)
-        out = []
-        for _ in range(4):
-            logits = lm.forward(params, cfg, seq)
-            nxt = int(jnp.argmax(logits[0, -1]))
-            out.append(nxt)
-            seq = jnp.concatenate([seq, jnp.asarray([[nxt]], jnp.int32)], axis=1)
-        assert req.tokens_out == out
+        assert max(ev.occupancy for ev in eng.service_log if ev.phase == "decode") == slots
+        # reference greedy, one prompt at a time
+        refs = []
+        for prompt in prompts:
+            seq = jnp.asarray(prompt[None], jnp.int32)
+            out = []
+            for _ in range(4):
+                logits = lm.forward(params, cfg, seq)
+                nxt = int(jnp.argmax(logits[0, -1]))
+                out.append(nxt)
+                seq = jnp.concatenate([seq, jnp.asarray([[nxt]], jnp.int32)], axis=1)
+            refs.append(out)
+        assert [req.tokens_out for req in reqs] == refs
+        assert len({tuple(r) for r in refs}) == slots
 
     def test_service_stats_collected(self, engine):
         cfg, eng = engine
