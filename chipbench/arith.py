@@ -1,4 +1,5 @@
-"""Operations and bytes that the algorithm needs, from a configuration's shapes.
+"""Operations and bytes that the algorithm needs, from a configuration's
+shapes: the dense family's arithmetic (``chipbench/families/dense.py``).
 
 Copied from the program's FLOP arithmetic (``2N`` per token over the
 parameters that enter a matmul, the input-embedding gather excluded and the

@@ -41,14 +41,15 @@ def reading(cell: dict, seed: int, seconds: float, control: bool, *, model=None,
     """One seed's checks for the program and, with ``control``, for the fp8
     control, each with its ``correct`` against ``limit`` (by default the
     configuration's)."""
-    from chipbench import harness
+    from chipbench import families, harness
 
     conf = harness.load_config(cell["config"])
     m = dict(model or conf["model"], name=cell["config"])
     mix = mix or harness.load_mix(cell["traffic"])
     engine = harness.build_engine(m, seed)
     harness.warm_up(engine, mix)
-    recs, _, _, _ = harness.drive(engine, harness.Load(mix, seed, m["vocab_size"]), seconds)
+    recs, _, _, _ = harness.drive(engine, harness.Load(mix, seed, m["vocab_size"]), seconds,
+                                  counters=families.of(m).tick_counters)
     del engine
     gc.collect()
     sample = harness.check_sample(recs, seed)

@@ -1,7 +1,10 @@
 """The benchmark's harness: one cell, one seed, one measured window.
 
 Everything a cell needs is found by name: the configuration in
-``chipbench/configs/<config>.json``, the traffic mix in
+``chipbench/configs/<config>.json``, its model family (everything that
+depends on the layer kinds: the program's config, the weight layout, the
+reference's layers, the arithmetic, the tick counters) in
+``chipbench/families/<family>.py``, the traffic mix in
 ``chipbench/traffic/<traffic>.json``, and each metric's reader in
 ``chipbench/metrics/<metric>.py``, whose ``read(run)`` returns a number or
 ``None`` when the run holds nothing for it to read.
@@ -78,6 +81,7 @@ class Tick:
     end: float
     events: list  # the engine's ServiceEvents of this tick
     decode_kv: list  # per decoded token, the valid cache positions it read
+    counters: dict = field(default_factory=dict)  # the family's tick_counters after it
 
 
 @dataclass
@@ -94,7 +98,7 @@ class Run:
     recs: list
     ticks: list  # ticks that started inside the window
     traced_ticks: int  # how many of them, from the first, the trace holds
-    trace: dict  # reduced profiler trace; empty without --trace 1
+    trace: dict  # xplane.reduce of the trace, with "scopes"; empty without --trace 1
 
 
 # ---------------------------------------------------------------------------
@@ -124,6 +128,11 @@ def load_config(name: str) -> dict:
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
 
 
+def config_names() -> list[str]:
+    """Every configuration file's name, listed in BENCHMARK.json or not."""
+    return sorted(p.stem for p in (BENCH / "configs").glob("*.json"))
+
+
 def load_mix(name: str) -> T.Mix:
     return T.load_mix(BENCH / "traffic" / f"{name}.json")
 
@@ -142,26 +151,14 @@ def reader(name: str):
 # ---------------------------------------------------------------------------
 
 
-def program_config(m: dict):
-    from repro.configs.base import LayerSpec, ModelConfig
-
-    keys = ("d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff", "vocab_size",
-            "num_superblocks", "gated_mlp", "mlp_act", "rope_theta", "norm_eps", "dtype")
-    return ModelConfig(name=m["name"], family="dense",
-                       superblock=tuple(LayerSpec(*k) for k in m["layers"]),
-                       **{k: m[k] for k in keys})
-
-
 def build_engine(m: dict, seed: int):
     import jax
 
-    from chipbench import weights
+    from chipbench import families, weights
     from repro.models import lm
     from repro.serving.engine import Engine, ServeConfig
 
-    if [list(k) for k in m["layers"]] != [["attn", "mlp"]]:
-        raise ValueError("the weight layout covers one attention+MLP layer kind")
-    cfg = program_config(m)
+    cfg = families.of(m).program_config(m)
     params = weights.served_params(m, seed)
     shape = lambda t: jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), t)
     want = lm.abstract_model(cfg)
@@ -237,7 +234,7 @@ def _annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def _tick(engine, clock, live: list, ticks: list) -> None:
+def _tick(engine, clock, live: list, ticks: list, counters=None) -> None:
     n0 = len(engine.service_log)
     start = clock()
     with _annotate("tick"):
@@ -255,13 +252,15 @@ def _tick(engine, clock, live: list, ticks: list) -> None:
                 kv.append(rec.prompt_len + n - 1)
         if rec.done:
             live.remove(rec)
-    ticks.append(Tick(start, end, events, kv))
+    ticks.append(Tick(start, end, events, kv, counters(engine) if counters else {}))
 
 
-def drive(engine, load: Load, seconds: float, *, trace_dir: Path | None = None):
-    """The measured window, then the drain. Returns (recs, the ticks that
-    started inside the window, how many of them the trace holds, drain
-    seconds)."""
+def drive(engine, load: Load, seconds: float, *, trace_dir: Path | None = None,
+          counters=None):
+    """The measured window, then the drain. ``counters(engine)`` is read
+    after each tick (the family's ``tick_counters``). Returns (recs, the
+    ticks that started inside the window, how many of them the trace holds,
+    drain seconds)."""
     import jax
 
     recs: list[Rec] = []
@@ -279,7 +278,7 @@ def drive(engine, load: Load, seconds: float, *, trace_dir: Path | None = None):
             tracing, traced = False, len(ticks)
         with _annotate("generate"):
             load.feed(engine, recs, live)
-        _tick(engine, clock, live, ticks)
+        _tick(engine, clock, live, ticks, counters)
     if tracing:
         jax.profiler.stop_trace()
         traced = len(ticks)
@@ -291,7 +290,7 @@ def drive(engine, load: Load, seconds: float, *, trace_dir: Path | None = None):
     live[:] = [r for r in live if r.req.t_admit is not None]
     t_close = clock()
     while live and clock() - t_close < DRAIN_S:
-        _tick(engine, clock, live, ticks)
+        _tick(engine, clock, live, ticks, counters)
     return recs, window_ticks, traced, clock() - t_close
 
 
@@ -374,8 +373,11 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, t_s
     ``mix`` replace the cell's files (small sizes for tests on the CPU)."""
     import jax
 
+    from chipbench import families, scopes
+
     conf = load_config(cell["config"])
     m = dict(model or conf["model"], name=cell["config"])
+    family = families.of(m)
     mix = mix or load_mix(cell["traffic"])
     limit = conf["check"]["max_logit_gap"]
     clock = CompileClock()
@@ -396,7 +398,8 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, t_s
         trace_dir.mkdir(parents=True)
     before = clock.snapshot()
     setup_s = time.perf_counter() - t_start
-    recs, ticks, traced, drain_s = drive(engine, load, seconds, trace_dir=trace_dir)
+    recs, ticks, traced, drain_s = drive(engine, load, seconds, trace_dir=trace_dir,
+                                         counters=family.tick_counters)
     compiles = {k: v - before.get(k, 0) for k, v in clock.snapshot().items()}
 
     dev = jax.devices()[0]
@@ -411,6 +414,11 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, t_s
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = reduced["window_s"]
     trace_read_s = time.perf_counter() - t_read
+    t_scopes, before_scopes = time.perf_counter(), clock.snapshot()
+    if reduced:
+        reduced["scopes"] = scopes.program_scopes(engine, mix)
+    scope_compiles = {k: v - before_scopes.get(k, 0) for k, v in clock.snapshot().items()}
+    scopes_s = time.perf_counter() - t_scopes
 
     run = Run(cell["name"], seed, seconds, setup_s, m, mix, peak, recs, ticks, traced, reduced)
     metrics = {}
@@ -430,6 +438,9 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, t_s
     diag["setup_parts_s"] = stamps
     diag["setup_compiles"] = before
     diag["trace_read_s"] = trace_read_s
+    diag["scope_map_s"] = scopes_s
+    diag["scope_map_compiles"] = scope_compiles
+    diag["program_time"] = scopes.coverage(reduced)
     out_dir.mkdir(parents=True, exist_ok=True)
     (out_dir / f"{cell['name']}.seed{seed}.trace{int(trace)}.json").write_text(
         json.dumps(diag, indent=1))
@@ -453,10 +464,8 @@ def _reduce_trace(trace_dir: Path) -> dict:
     devices, modules, host = xplane.read_events(path)
     if not host:
         return {}
-    out = xplane.reduce(devices, host, min(s for _, s, _ in host), max(e for _, _, e in host))
-    if out:
-        out["modules"] = xplane.module_totals(next(iter(modules.values()), []))
-    return out
+    return xplane.reduce(devices, host, min(s for _, s, _ in host), max(e for _, _, e in host),
+                         programs=modules)
 
 
 def _top(pairs, k: int = 5) -> list:

@@ -1,13 +1,11 @@
 """Plain reference of the served decoders, and its lower-precision control.
 
 Straight ``jax.numpy`` in float32 at ``Precision.HIGHEST``, one full causal
-forward pass over a prompt and the tokens that were served for it, a layer
-at a time, with weights regenerated from the seed (``weights.layer_f32``):
-it takes nothing that the program made. It follows the configuration file's
-equations (pre-norm RMSNorm with ``(1 + scale)``, rotate-half RoPE, GQA or
-MHA softmax attention, plain GELU(tanh) or SwiGLU MLP, untied head), which
-are the program's, departures from the published models included; the
-configuration files list those departures.
+forward pass over a prompt and the tokens that were served for it, with
+weights regenerated from the seed (``weights.layer_f32``): it takes nothing
+that the program made. The layers are the model family's
+(``chipbench/families/<family>.py`` ``hidden``), built from the pieces
+below; the final RMSNorm and the untied head are here.
 
 ``fp8=True`` is the control: every weight matmul takes both operands through
 float8 e4m3 with a per-tensor scale, the step below the served bfloat16.
@@ -21,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import weights
+from chipbench import families, weights
 
 HI = jax.lax.Precision.HIGHEST
 Q_CHUNK = 512  # queries per attention block
@@ -33,29 +31,18 @@ def _fp8(x):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _mm(a, b, fp8: bool):
+def mm(a, b, fp8: bool):
     if fp8:
         a, b = _fp8(a), _fp8(b)
     return jnp.matmul(a, b, precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + scale)
 
 
-def _rope(x, theta):
-    """x: (T, H, hd), positions 0..T-1, rotate-half convention."""
-    T, _, hd = x.shape
-    half = hd // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
-    sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _attention(q, k, v):
+def attention(q, k, v):
     """q: (T, H, hd); k, v: (T, K, hd). Causal softmax attention."""
     T, H, hd = q.shape
     G = H // k.shape[1]
@@ -73,25 +60,6 @@ def _attention(q, k, v):
     return out.reshape(T, H, hd)
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _layer(xs, w, mf, fp8):
-    """xs: (T, d) float32 -> the same after one decoder layer."""
-    m = dict(mf)
-    H, K, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    T = xs.shape[0]
-    h = _rms(xs, w["norm1"], m["norm_eps"])
-    q = _rope(_mm(h, w["attn/wq"], fp8).reshape(T, H, hd), m["rope_theta"])
-    k = _rope(_mm(h, w["attn/wk"], fp8).reshape(T, K, hd), m["rope_theta"])
-    v = _mm(h, w["attn/wv"], fp8).reshape(T, K, hd)
-    xs = xs + _mm(_attention(q, k, v).reshape(T, H * hd), w["attn/wo"], fp8)
-    h = _rms(xs, w["norm2"], m["norm_eps"])
-    if m["gated_mlp"]:
-        a = jax.nn.silu(_mm(h, w["mlp/wg"], fp8)) * _mm(h, w["mlp/wi"], fp8)
-    else:
-        a = jax.nn.gelu(_mm(h, w["mlp/wi"], fp8), approximate=True)
-    return xs + _mm(a, w["mlp/wo"], fp8)
-
-
 @partial(jax.jit, static_argnums=(3, 4))
 def _head(xs, top, targets, eps, fp8):
     """xs: (T, d); targets: (T, J) token ids. Returns, per position, the best
@@ -100,7 +68,7 @@ def _head(xs, top, targets, eps, fp8):
 
     def rows(args):
         xb, tg = args
-        logits = _mm(_rms(xb, top["final_norm"], eps), top["embed/unembed"], fp8)
+        logits = mm(rms(xb, top["final_norm"], eps), top["embed/unembed"], fp8)
         return (logits.max(-1), jnp.take_along_axis(logits, tg, axis=-1),
                 logits.argmax(-1).astype(jnp.int32))
 
@@ -108,22 +76,6 @@ def _head(xs, top, targets, eps, fp8):
     best, picked, first = jax.lax.map(
         rows, (xs.reshape(n, Q_CHUNK, d), targets.reshape(n, Q_CHUNK, -1)))
     return best.reshape(T), picked.reshape(T, -1), first.reshape(T)
-
-
-def _static(m: dict):
-    keys = ("d_model", "num_heads", "num_kv_heads", "head_dim", "gated_mlp",
-            "rope_theta", "norm_eps")
-    return tuple((k, m[k]) for k in keys)
-
-
-def _hidden(m: dict, seed: int, rows: list[np.ndarray], top: dict, fp8: bool):
-    """Final hidden states of token rows, a layer at a time: each layer's
-    weights are made once and applied to every row."""
-    xs = [jnp.take(top["embed/embedding"], jnp.asarray(t), axis=0) for t in rows]
-    for layer in range(m["num_superblocks"]):
-        w = weights.layer_f32(m, seed, layer)
-        xs = [_layer(x, w, _static(m), fp8) for x in xs]
-    return xs
 
 
 def served_gaps(m: dict, seed: int, prompts: list[np.ndarray],
@@ -136,6 +88,7 @@ def served_gaps(m: dict, seed: int, prompts: list[np.ndarray],
     served tokens form one row, padded to a multiple of ``PAD``.
     """
     top = weights.top_f32(m, seed)
+    hidden = families.of(m).hidden
     rows, targets, spans = [], [], []
     for p, s in zip(prompts, served):
         L, n = len(p), len(s)
@@ -149,11 +102,11 @@ def served_gaps(m: dict, seed: int, prompts: list[np.ndarray],
         targets.append(tg)
         spans.append(slice(L - 1, L - 1 + n))
     if fp8_control:
-        for r, x in enumerate(_hidden(m, seed, rows, top, True)):
+        for r, x in enumerate(hidden(m, seed, rows, top, True)):
             targets[r][:, 1] = np.asarray(
                 _head(x, top, jnp.asarray(targets[r]), m["norm_eps"], True)[2])
     gaps, ctl_gaps = [], []
-    for r, x in enumerate(_hidden(m, seed, rows, top, False)):
+    for r, x in enumerate(hidden(m, seed, rows, top, False)):
         best, picked, _ = (np.asarray(a) for a in
                            _head(x, top, jnp.asarray(targets[r]), m["norm_eps"], False))
         gaps.append(best[spans[r]] - picked[spans[r], 0])

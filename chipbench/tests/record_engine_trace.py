@@ -27,9 +27,9 @@ HERE = Path(__file__).resolve().parent
 sys.path[0] = str(HERE.parents[1])
 sys.path.insert(1, str(HERE.parents[1] / "src"))
 
-MODEL = dict(name="small", d_model=64, num_heads=4, num_kv_heads=4, head_dim=16, d_ff=96,
-             vocab_size=512, num_superblocks=2, layers=[["attn", "mlp"]], gated_mlp=True,
-             mlp_act="silu", rope_theta=10000.0, norm_eps=1e-6, dtype="bfloat16")
+MODEL = dict(name="small", family="dense", d_model=64, num_heads=4, num_kv_heads=4,
+             head_dim=16, d_ff=96, vocab_size=512, num_superblocks=2, layers=[["attn", "mlp"]],
+             gated_mlp=True, mlp_act="silu", rope_theta=10000.0, norm_eps=1e-6, dtype="bfloat16")
 SEED = 2**33 + 7
 TICKS = 24
 DST = HERE / "data" / "engine.xplane.pb"

@@ -1,8 +1,10 @@
-"""FLOPs and bytes from the configurations' shapes, against hand counts."""
+"""FLOPs and bytes from the configurations' shapes, against hand counts,
+and the model families' arithmetic of every configuration file."""
 
 import pytest
 
-from chipbench import arith, harness
+from chipbench import arith, families, harness
+from chipbench.families import dense
 
 # per layer: wq + wk + wv + wo + MLP; then the head (d x V)
 SC2 = 30 * (3072 * 3072 + 2 * 3072 * 256 + 3072 * 3072 + 2 * 3072 * 12288) + 3072 * 49152
@@ -44,3 +46,21 @@ def test_bytes_per_decode_step(case):
     weights = 2 * (total - embed + m["d_model"])
     assert arith.decode_bytes(m, 0) == weights
     assert arith.decode_bytes(m, 4096) == weights + 4096 * kv
+
+
+@pytest.mark.parametrize("name", harness.config_names())
+def test_prefill_flops_grow_with_the_prompt(name):
+    m = harness.load_config(name)["model"]
+    flops = [families.of(m).prefill_flops(m, L) for L in (1, 512, 2048, 4096)]
+    assert 0 < flops[0] and flops == sorted(set(flops))
+
+
+@pytest.mark.parametrize("name", [n for n in harness.config_names()
+                                  if harness.load_config(n)["model"]["family"] == "dense"])
+def test_the_dense_family_counts_as_arith_whatever_the_counters(name):
+    m = harness.load_config(name)["model"]
+    for n, counters in ((0, {}), (2049, {"position": 7}), (4095, {})):
+        assert dense.decode_flops(m, n, counters) == arith.decode_flops(m, n)
+        assert dense.decode_bytes(m, n, counters) == arith.decode_bytes(m, n)
+        assert dense.prefill_flops(m, n + 1) == arith.prefill_flops(m, n + 1)
+    assert dense.tick_counters(engine=None) == {}

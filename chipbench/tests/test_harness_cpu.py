@@ -18,22 +18,15 @@ from repro.models import lm
 from repro.serving.engine import Engine
 
 SPEC = harness.load_spec()
-SMALL = {
-    "starcoder2_3b": dict(d_model=64, num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,
-                          vocab_size=512, num_superblocks=2, layers=[["attn", "mlp"]],
-                          gated_mlp=False, mlp_act="gelu", rope_theta=999999.4420358813,
-                          norm_eps=1e-5, dtype="bfloat16"),
-    "deepseek_7b_15l": dict(d_model=64, num_heads=4, num_kv_heads=4, head_dim=16, d_ff=96,
-                            vocab_size=512, num_superblocks=2, layers=[["attn", "mlp"]],
-                            gated_mlp=True, mlp_act="silu", rope_theta=10000.0,
-                            norm_eps=1e-6, dtype="bfloat16"),
-}
+# each configuration file's small model block and the traffic it is
+# rehearsed on, so that every configuration's code path (MHA with SwiGLU,
+# GQA with GELU, ...) stays under test, listed in BENCHMARK.json or not
+CONFIGS = {name: harness.load_config(name) for name in harness.config_names()}
+SMALL = {name: conf["small"] for name, conf in CONFIGS.items()}
 SEED = 2**33 + 1
 SMALL_MIX = T.Mix(name="small", prompt_lens=(8, 24), prompt_weights=(1, 1), output_mean=6,
                   output_cap=20)
-# one cell per configuration, listed in BENCHMARK.json or not, so that both
-# configurations' code paths (MHA with SwiGLU, GQA with GELU) stay under test
-CELLS = ["deepseek_7b_15l.doc_qa", "starcoder2_3b.batch_gen"]
+CELLS = [f"{name}.{conf['rehearsal_traffic']}" for name, conf in CONFIGS.items()]
 # The configurations' limits hold at their own widths. At these small widths
 # the program's widest gap read 0 to 0.025 and the fp8 control's 0.09 to
 # 0.18 (both configurations, 1 s and 3 s windows, seeds 7, 2**31 + 5 and

@@ -40,8 +40,64 @@ def test_configurations_resolve(conf):
     assert any(c["config"] == conf["name"] for c in SPEC["workloads"])
     limit = data["check"]["max_logit_gap"]
     assert 0 < limit < float("inf")
-    for key in ("d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff", "vocab_size"):
-        assert key not in conf["reduced"]  # widths are never cut
+    assert broken_floors(data) == []
+
+
+# The model-configs guide, section 4: no width is ever cut (hidden size, head
+# sizes, feed-forward and expert widths, experts per token, window and state
+# sizes); a sliced vocabulary keeps at least an eighth, and a layer with
+# experts holds at least 8 of them here.
+WIDTHS = {"hidden_size", "d_model", "intermediate_size", "d_ff", "moe_intermediate_size",
+          "num_experts_per_tok", "sliding_window", "window_size", "d_state", "mamba_d_state",
+          "d_conv", "mamba_d_conv", "expand", "mamba_expand"}
+EXPERT_COUNTS = ("num_experts", "num_local_experts", "n_routed_experts")
+
+
+def is_width(key: str) -> bool:
+    return key in WIDTHS or key.endswith(("_dim", "_rank", "hidden_size",
+                                          "intermediate_size", "_d_state", "_expand"))
+
+
+def broken_floors(data: dict) -> list[str]:
+    """What a configuration file's ``reduced`` cuts below the floors."""
+    cut, published, as_run = data["reduced"], data["published"], data["as_run"]
+    out = [f"{k}: a width" for k in cut if is_width(k)]
+    out += [f"{k}: not stated as published and as run" for k in cut
+            if k not in published or k not in as_run]
+    if "vocab_size" in cut and "vocab_size" in as_run:
+        if 8 * as_run["vocab_size"] < published["vocab_size"]:
+            out.append("vocab_size: under an eighth of the published vocabulary")
+    out += [f"{k}: fewer than 8 experts held" for k in EXPERT_COUNTS
+            if k in cut and as_run.get(k, 0) < 8]
+    return out
+
+
+@pytest.mark.parametrize("name", harness.config_names())
+def test_every_configuration_file_keeps_the_floors(name):
+    data = harness.load_config(name)
+    assert broken_floors(data) == []
+    assert data["model"]["family"] == data["small"]["family"]
+    harness.load_mix(data["rehearsal_traffic"])
+
+
+@pytest.mark.parametrize("cut, as_run, broken", [
+    (["vocab_size"], {"vocab_size": 16384}, []),  # a quarter of 65536
+    (["vocab_size"], {"vocab_size": 4096}, ["vocab_size: under an eighth of the published "
+                                            "vocabulary"]),
+    (["num_experts"], {"num_experts": 8}, []),
+    (["num_experts"], {"num_experts": 4}, ["num_experts: fewer than 8 experts held"]),
+    (["hidden_size"], {"hidden_size": 2048}, ["hidden_size: a width"]),
+    (["moe_intermediate_size"], {"moe_intermediate_size": 7168},
+     ["moe_intermediate_size: a width"]),
+    (["head_dim"], {"head_dim": 64}, ["head_dim: a width"]),
+    (["mamba_d_state"], {"mamba_d_state": 8}, ["mamba_d_state: a width"]),
+    (["num_hidden_layers"], {}, ["num_hidden_layers: not stated as published and as run"]),
+])
+def test_floors_refuse_a_cut_width_and_too_small_a_share(cut, as_run, broken):
+    published = {"vocab_size": 65536, "num_experts": 16, "hidden_size": 4096,
+                 "moe_intermediate_size": 14336, "head_dim": 128, "mamba_d_state": 16,
+                 "num_hidden_layers": 32}
+    assert broken_floors({"reduced": cut, "published": published, "as_run": as_run}) == broken
 
 
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda c: c["name"])

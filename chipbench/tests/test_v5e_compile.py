@@ -1,7 +1,8 @@
-"""Compile each configuration's timed programs and its reference for a
+"""Compile each configuration file's timed programs and its reference for a
 described v5e chip (no chip needed): the seeded weights, prefill at the
-mix's longest prompt, decode at ``max_seq=4096``, and one reference layer
-and the reference head at 4096 positions. Each must fit one chip's memory.
+longest prompt of the traffic the file names for its rehearsal, decode at
+``max_seq=4096``, and the reference's first superblock and head at 4096
+positions. Each must fit one chip's memory.
 """
 
 import os
@@ -10,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import harness, reference, weights
+from chipbench import families, harness, reference, weights
 from chipbench import traffic as T
 
 HBM = 16e9
@@ -35,17 +36,16 @@ def _bytes(compiled) -> float:
     return ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
 
 
-CELLS = {"starcoder2_3b": "batch_gen", "deepseek_7b_15l": "doc_qa"}
-
-
-@pytest.mark.parametrize("name", sorted(CELLS))
+@pytest.mark.parametrize("name", harness.config_names())
 def test_timed_programs_and_reference_compile_for_v5e(name, one_chip):
     from repro.models import lm
     from repro.models.params import init_params
 
-    m = dict(harness.load_config(name)["model"], name=name)
-    mix = harness.load_mix(CELLS[name])
-    cfg = harness.program_config(m)
+    conf = harness.load_config(name)
+    m = dict(conf["model"], name=name)
+    mix = harness.load_mix(conf["rehearsal_traffic"])
+    family = families.of(m)
+    cfg = family.program_config(m)
     sds = lambda x, dt=None: jax.ShapeDtypeStruct(x.shape, dt or x.dtype, sharding=one_chip)
 
     key = weights.seed_key(1)
@@ -67,12 +67,12 @@ def test_timed_programs_and_reference_compile_for_v5e(name, one_chip):
     assert _bytes(decode) < HBM
     assert _bytes(prefill) + _bytes(decode) - weight_bytes < HBM
 
-    lay = weights.layout(m)
-    w = {k.removeprefix("blocks/"): jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-         for k, (s, st, _) in lay.items() if st}
+    # the reference's first superblock over one row, its weights made inside
     top = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-           for k, (s, st, _) in lay.items() if not st}
+           for k, (s, st, _) in weights.layout(m).items() if not st}
+    one = dict(m, num_superblocks=1)
+    layer = jax.jit(lambda top, row: family.hidden(one, 1, [row], top, False)[0]).lower(
+        top, scalar((T.MAX_SEQ,))).compile()
     x = jax.ShapeDtypeStruct((T.MAX_SEQ, m["d_model"]), jnp.float32, sharding=one_chip)
-    layer = reference._layer.lower(x, w, reference._static(m), False).compile()
     head = reference._head.lower(x, top, scalar((T.MAX_SEQ, 2)), m["norm_eps"], False).compile()
     assert _bytes(layer) + _bytes(head) < HBM / 2

@@ -2,12 +2,13 @@
 
 import jax
 import numpy as np
+import pytest
 
-from chipbench import harness, weights
+from chipbench import families, harness, weights
 
-SMALL = dict(name="small", d_model=64, num_heads=4, num_kv_heads=2, head_dim=16, d_ff=96,
-             vocab_size=512, num_superblocks=3, layers=[["attn", "mlp"]], gated_mlp=True,
-             mlp_act="silu", rope_theta=10000.0, norm_eps=1e-6, dtype="bfloat16")
+SMALL = dict(name="small", family="dense", d_model=64, num_heads=4, num_kv_heads=2,
+             head_dim=16, d_ff=96, vocab_size=512, num_superblocks=3, layers=[["attn", "mlp"]],
+             gated_mlp=True, mlp_act="silu", rope_theta=10000.0, norm_eps=1e-6, dtype="bfloat16")
 SEED = 2**35 + 77
 
 
@@ -46,3 +47,17 @@ def test_seeded_tree_matches_the_program():
     # build_engine refuses a tree whose structure or shapes differ
     eng = harness.build_engine(SMALL, SEED)
     assert eng.cfg.num_layers == 3 and eng.cfg.gated_mlp
+
+
+@pytest.mark.parametrize("name", harness.config_names())
+def test_layout_is_the_programs_tree_at_published_widths(name):
+    from repro.models import lm
+
+    m = dict(harness.load_config(name)["model"], name=name)
+    n = m["num_superblocks"]
+    tree = weights.nest({path: jax.ShapeDtypeStruct((n, *shape) if stacked else shape, m["dtype"])
+                         for path, (shape, stacked, _) in weights.layout(m).items()})
+    want = lm.abstract_model(families.of(m).program_config(m))
+    assert jax.tree.structure(tree) == jax.tree.structure(want)
+    assert all(a.shape == b.shape and a.dtype == b.dtype
+               for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(want)))

@@ -39,6 +39,7 @@ def test_ops_are_named_after_their_program():
     assert xplane.short_name(op, "jit__lambda(13579960387038585310)") == \
         "jit__lambda/%fusion.95 bf16[12288]"
     assert xplane.short_name("%while.12 = (s32[]{:T(128)}, bf16[1])", "?") == "?/%while.12 (s32[]"
+    assert xplane.op_key(op) == "%fusion.95 bf16[12288]"
 
 
 def test_busy_is_averaged_over_device_planes():
@@ -79,3 +80,22 @@ def test_trace_recorded_on_the_chip():
     (name, (runs, secs)), = xplane.module_totals(modules["/device:TPU:0"]).items()
     assert name.startswith("jit__lambda(") and runs == 12
     assert 12 * 150e-6 < secs < 12 * 250e-6  # each run takes about 0.19 ms
+
+
+def test_op_seconds_are_kept_per_program_run_name():
+    # two runs of jit_a (a loop holding one op) and one of jit_b; one op ran outside
+    ops = [("jit_a/%while.1 (s32[]", 0, 40), ("jit_a/%fusion.2 bf16[8]", 10, 30),
+           ("jit_b/%fusion.2 f32[4]", 60, 70), ("jit_a/%while.1 (s32[]", 100, 120),
+           ("jit_a/%fusion.2 bf16[8]", 105, 110), ("?/%copy.1 f32[2]", 200, 201)]
+    runs = [("jit_a(11)", 0, 40), ("jit_b(12)", 55, 75), ("jit_a(11)", 100, 125)]
+    out = xplane.reduce({"/device:TPU:0": ops}, [], 50, 150, programs={"/device:TPU:0": runs})
+    assert out["op_seconds"] == {
+        "jit_a(11)": {"%while.1 (s32[]": pytest.approx(35e-9),
+                      "%fusion.2 bf16[8]": pytest.approx(25e-9)},
+        "jit_b(12)": {"%fusion.2 f32[4]": pytest.approx(10e-9)},
+        "?": {"%copy.1 f32[2]": pytest.approx(1e-9)}}
+    assert out["modules"] == {"jit_a(11)": [2, pytest.approx(65e-9)],
+                              "jit_b(12)": [1, pytest.approx(20e-9)]}
+    # the window's breakdown is the same with or without the programs
+    plain = xplane.reduce({"/device:TPU:0": ops}, [], 50, 150)
+    assert {k: out[k] for k in plain} == plain and "op_seconds" not in plain

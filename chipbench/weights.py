@@ -8,9 +8,9 @@ deviation. The draw is built from raw bits with a single rounding step
 a time, in float32) hold the same numbers bit for bit however XLA fuses
 the two programs.
 
-The layout below names the leaves of the served model's parameter tree.
-It is written from the configuration file alone; the harness checks it
-against the program's own abstract tree before anything runs.
+The model family's layout names the leaves of the served model's parameter
+tree. It is written from the configuration file alone; the harness checks
+it against the program's own abstract tree before anything runs.
 """
 
 from __future__ import annotations
@@ -20,6 +20,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import families
 
 NORM_STD = 0.1  # norm scales enter as (1 + scale)
 EMBED_STD = 1.0
@@ -34,26 +36,9 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def layout(m: dict) -> dict[str, tuple[tuple[int, ...], bool, float]]:
-    """path -> (shape of one layer's leaf, stacked over layers, std)."""
-    d, H, K, hd = m["d_model"], m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    f, V = m["d_ff"], m["vocab_size"]
-    q, kv = H * hd, K * hd
-    out = {
-        "embed/embedding": ((V, d), False, EMBED_STD),
-        "embed/unembed": ((d, V), False, d ** -0.5),
-        "final_norm": ((d,), False, NORM_STD),
-        "blocks/norm1": ((d,), True, NORM_STD),
-        "blocks/attn/wq": ((d, q), True, d ** -0.5),
-        "blocks/attn/wk": ((d, kv), True, d ** -0.5),
-        "blocks/attn/wv": ((d, kv), True, d ** -0.5),
-        "blocks/attn/wo": ((q, d), True, q ** -0.5),
-        "blocks/norm2": ((d,), True, NORM_STD),
-        "blocks/mlp/wi": ((d, f), True, d ** -0.5),
-        "blocks/mlp/wo": ((f, d), True, f ** -0.5),
-    }
-    if m["gated_mlp"]:
-        out["blocks/mlp/wg"] = ((d, f), True, d ** -0.5)
-    return out
+    """path -> (shape of one superblock's leaf, stacked over superblocks,
+    std): the model family's layout (``chipbench/families``)."""
+    return families.of(m).layout(m)
 
 
 def _leaf_key(key, path: str):
@@ -96,22 +81,30 @@ def served_params(m: dict, seed: int):
 
 def nest(leaves: dict):
     """Flat ``a/b/c`` paths -> the program's tree: a dict per level, and the
-    stacked block leaves inside a one-element tuple (one layer kind)."""
+    block leaves in a tuple with one dict per superblock position
+    (``blocks/<i>/...``; a block path without a position is position 0)."""
     tree: dict = {}
+    positions: dict[int, dict] = {}
     for path, leaf in leaves.items():
         parts = path.split("/")
         node = tree
         if parts[0] == "blocks":
-            node = tree.setdefault("blocks", ({},))[0]
-            parts = parts[1:]
+            at = parts[1].isdigit()
+            node = positions.setdefault(int(parts[1]) if at else 0, {})
+            parts = parts[2 if at else 1:]
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = leaf
+    if positions:
+        if sorted(positions) != list(range(len(positions))):
+            raise ValueError(f"superblock positions {sorted(positions)} leave a gap")
+        tree["blocks"] = tuple(positions[i] for i in range(len(positions)))
     return tree
 
 
 def layer_f32(m: dict, seed: int, layer: int) -> dict:
-    """One layer's leaves, rounded to the served dtype, held in float32."""
+    """One superblock's leaves, rounded to the served dtype, held in
+    float32, keyed by their paths less ``blocks/``."""
     return _layer_f32(_frozen(m), seed_key(seed), jnp.uint32(layer))
 
 
@@ -121,9 +114,12 @@ def top_f32(m: dict, seed: int) -> dict:
 
 
 def _frozen(m: dict):
-    return tuple(sorted((k, m[k]) for k in
-                        ("d_model", "num_heads", "num_kv_heads", "head_dim",
-                         "d_ff", "vocab_size", "gated_mlp", "dtype")))
+    """The model block as a hashable static argument."""
+    return tuple(sorted((k, _hashable(v)) for k, v in m.items()))
+
+
+def _hashable(v):
+    return tuple(_hashable(x) for x in v) if isinstance(v, (list, tuple)) else v
 
 
 def _rounded(x, dtype):

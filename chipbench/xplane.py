@@ -7,7 +7,9 @@ ran them; busy time is the union of their intervals, so nested events (a
 loop and the ops of its body) count once, and the top ops are ranked by
 self time. Each run of a compiled program is one event of the ``XLA
 Modules`` line, named with its fingerprint, so two programs do not share a
-name. Host spans are the benchmark's own ``jax.profiler.TraceAnnotation``
+name; each op's self time is also kept per program run name
+(``op_seconds``), for readers that sum the ops under a source scope
+(``chipbench/scopes.py``). Host spans are the benchmark's own ``jax.profiler.TraceAnnotation``
 events, found by name on the host plane. All times are nanoseconds on the
 profiler's clock.
 """
@@ -28,11 +30,17 @@ def newest_xplane(trace_dir: Path) -> Path | None:
     return files[-1] if files else None
 
 
+def op_key(op: str) -> str:
+    """``<op> <result type>``: the HLO instruction's name and the type it
+    returns, from the instruction's text (``%fusion.9 = bf16[64]{0} ...``)."""
+    head, _, rest = op.partition(" = ")
+    return f"{head} {rest.split('{')[0].split(' ')[0]}".rstrip()
+
+
 def short_name(op: str, module: str) -> str:
     """``<module>/<op> <result type>``: the module's name without its hash,
-    the HLO instruction's name and the type it returns."""
-    head, _, rest = op.partition(" = ")
-    return f"{module.split('(')[0]}/{head} {rest.split('{')[0].split(' ')[0]}".rstrip()
+    then :func:`op_key`."""
+    return f"{module.split('(')[0]}/{op_key(op)}"
 
 
 def read_events(path: Path, spans=HOST_SPANS):
@@ -93,19 +101,41 @@ def busy_ns(ops, lo, hi) -> float:
     return float(sum(e - s for s, e in union(clip([(s, e) for _, s, e in ops], lo, hi))))
 
 
-def self_times(ops, lo, hi):
-    """``(name, seconds)`` per op inside ``[lo, hi)``, less the time of the ops
-    nested in it (a loop holds its body's ops on the same line)."""
+def _self_ns(ops, lo, hi):
+    """``(name, start, self ns)`` per op inside ``[lo, hi)``: its time less
+    the time of the ops nested in it (a loop holds its body's ops on the
+    same line)."""
     out = []
-    stack: list[list] = []  # [name, end, self_ns]
+    stack: list[list] = []  # [name, end, self_ns, start]
     for name, s, e in sorted(clip_named(ops, lo, hi), key=lambda o: (o[1], -o[2])):
         while stack and stack[-1][1] <= s:
             out.append(stack.pop())
         if stack:
             stack[-1][2] -= min(e, stack[-1][1]) - s
-        stack.append([name, e, e - s])
+        stack.append([name, e, e - s, s])
     out += stack
-    return [(name, self_ns * 1e-9) for name, _, self_ns in out]
+    return [(name, s, self_ns) for name, _, self_ns, s in out]
+
+
+def self_times(ops, lo, hi):
+    """``(name, seconds)`` per op inside ``[lo, hi)``, less the time of the ops
+    nested in it."""
+    return [(name, self_ns * 1e-9) for name, _, self_ns in _self_ns(ops, lo, hi)]
+
+
+def op_seconds(ops, runs) -> dict:
+    """Per program run name (``jit_engine_decode(<fingerprint>)``, the
+    ``XLA Modules`` event that holds the op's start), each op's self seconds
+    over the whole trace, keyed by :func:`op_key`; ops that no run holds
+    are under ``?``."""
+    runs = sorted(runs, key=lambda r: r[1])
+    starts = [s for _, s, _ in runs]
+    out: dict = defaultdict(lambda: defaultdict(float))
+    for name, s, self_ns in _self_ns(ops, float("-inf"), float("inf")):
+        i = bisect.bisect_right(starts, s) - 1
+        program = runs[i][0] if i >= 0 and runs[i][2] > s else "?"
+        out[program][name.split("/", 1)[1]] += self_ns * 1e-9
+    return {p: dict(v) for p, v in out.items()}
 
 
 def clip_named(ops, lo, hi):
@@ -144,16 +174,23 @@ def idle_gaps(ops, host, lo, hi, k: int = 10):
     return named
 
 
-def reduce(devices: dict, host, lo, hi) -> dict:
+def reduce(devices: dict, host, lo, hi, programs: dict | None = None) -> dict:
     """Busy seconds averaged over the device planes, and the breakdown of the
-    first plane, over the window ``[lo, hi)``."""
+    first plane, over the window ``[lo, hi)``. With ``programs`` (the runs
+    that :func:`read_events` gives per plane) also the first plane's
+    ``op_seconds`` and ``modules`` (:func:`module_totals`), over the whole
+    trace."""
     if not devices or not any(devices.values()):
         return {}
     busy = [busy_ns(ops, lo, hi) for ops in devices.values()]
-    first = next(iter(devices.values()))
-    return {
+    plane, first = next(iter(devices.items()))
+    out = {
         "busy_s": sum(busy) / len(busy) * 1e-9,
         "window_s": (hi - lo) * 1e-9,
         "device_ops": top_ops(first, lo, hi),
         "idle_gaps": idle_gaps(first, host, lo, hi),
     }
+    if programs is not None:
+        out["op_seconds"] = op_seconds(first, programs.get(plane, []))
+        out["modules"] = module_totals(programs.get(plane, []))
+    return out
