@@ -65,14 +65,23 @@ class ModelConfig:
     gated_mlp: bool = True  # SwiGLU/GeGLU (3 mats) vs plain MLP (2 mats)
     mlp_act: str = "silu"  # "silu" | "gelu"
     # --- moe ---
-    num_experts: int = 0
+    num_experts: int = 0  # the router's width: every expert of the layer
     num_experts_per_tok: int = 0
+    # experts held by this model's parameters: ids expert_offset ..
+    # expert_offset + experts_held - 1 of num_experts (0 holds them all). The
+    # served layer routes over all num_experts and computes only the held
+    # experts' part (one chip's share under expert parallelism).
+    expert_offset: int = 0
+    experts_held: int = 0
+    moe_renormalize: bool = True  # renormalise the top-k gates to sum to 1
     capacity_factor: float = 1.25
     moe_group_size: int = 2048  # tokens per dispatch group (GShard G x S split)
     # --- ssm (mamba) ---
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
+    mamba_dt_rank: int = 0  # 0 -> ceil(d_model / 16)
+    mamba_inner_norms: bool = False  # Jamba: RMSNorm on dt, B and C after x_proj
     # --- encoder-decoder (seamless) ---
     encoder_layers: int = 0  # 0 -> decoder-only
     # --- modality frontend stub (vlm / audio) ---
@@ -126,6 +135,14 @@ class ModelConfig:
         return self.mamba_expand * self.d_model
 
     @property
+    def resolved_dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -152,6 +169,9 @@ class ModelConfig:
             num_superblocks=min(2, self.num_superblocks),
             num_experts=4 if self.num_experts else 0,
             num_experts_per_tok=min(self.num_experts_per_tok, 2),
+            expert_offset=0,
+            experts_held=0,
+            mamba_dt_rank=0,
             # untrained tiny routers are heavily skewed; give smoke tests
             # enough capacity that GShard dropping never fires
             capacity_factor=8.0,

@@ -20,8 +20,10 @@ CONFIG = register(ModelConfig(
     ),
     num_superblocks=4,
     num_experts=16, num_experts_per_tok=2, capacity_factor=1.25,
+    moe_renormalize=False,  # Jamba's router keeps the top-2 softmax weights as they are
     rope=False,  # Jamba uses NoPE on its attention layers
     mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+    mamba_dt_rank=256, mamba_inner_norms=True,  # dt/B/C RMSNorms of JambaMambaMixer
     grad_accum=8,  # measured: temp 18.7 GiB at accum 4 -> 13.6 at 8 (fits 16 GiB HBM)
     service_model="mm1",
     supports_long_context=True,

@@ -3,14 +3,25 @@
 One code path serves all 10 assigned architectures; the superblock pattern in
 the config decides which mixers/FFNs appear. The stack is scanned over
 superblocks (HLO O(1) in depth); ``cfg.scan_layers=False`` unrolls it for the
-roofline-accounting compiles (EXPERIMENTS.md §Roofline: XLA cost analysis
-counts while-loop bodies once — verified empirically — so totals are
-extrapolated from unrolled 1- and 2-superblock compiles).
+roofline-accounting compiles (XLA's cost analysis counts a while-loop body
+once, so totals are extrapolated from unrolled 1- and 2-superblock
+compiles).
 
 Modes:
   forward  — full-sequence logits (training)
   prefill  — full-sequence + build decode caches
   decode   — one token, consume/update caches
+
+Expert layers route with capacity in ``forward`` (``moe.moe_apply``) and
+without drops in ``prefill`` and ``decode`` (``moe.moe_dropless``, which
+also serves a share of the experts). Each mixer sub-block (its norm, the
+mixer, the residual add) runs under ``jax.named_scope(<mixer kind>)`` and
+each FFN sub-block under ``jax.named_scope(<ffn kind>)``, so a profile's ops
+map to the layer kind they belong to. The caches of an expert layer hold
+``routed``: per batch row, the int32 count of the (token, choice) pairs
+that the prefill or decode step which wrote them routed to each held
+expert. Nothing reads it back; it is how a step reports its routing
+without changing what it returns (:func:`expert_tokens`).
 """
 
 from __future__ import annotations
@@ -42,6 +53,7 @@ __all__ = [
     "decode_step",
     "loss_fn",
     "encode",
+    "expert_tokens",
 ]
 
 
@@ -125,6 +137,9 @@ def cache_template(
             c.update(XL.mlstm_cache_template(cfg, batch))
         elif spec.mixer == "slstm":
             c.update(XL.slstm_cache_template(cfg, batch))
+        if spec.ffn in ("moe", "moe_dense"):
+            c["routed"] = TSpec((batch, cfg.held_experts), ("cache_batch", None),
+                                init="zeros", dtype="int32")
         per_pos.append(c)
     return stack(tuple(per_pos), cfg.num_superblocks)
 
@@ -150,17 +165,22 @@ def num_params(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _apply_ffn(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+def _apply_ffn(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, mode: str):
+    """Returns (x, (batch, held) pairs routed to each held expert, or None)."""
     if spec.ffn == "none":
-        return x
+        return x, None
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     if spec.ffn == "mlp":
-        return x + mlp_apply(p["mlp"], h, cfg)
-    if spec.ffn == "moe":
-        return x + M.moe_apply(p["moe"], h, cfg)
+        return x + mlp_apply(p["mlp"], h, cfg), None
+    if spec.ffn not in ("moe", "moe_dense"):
+        raise ValueError(spec.ffn)
+    if mode == "forward":
+        y, routed = M.moe_apply(p["moe"], h, cfg), None
+    else:
+        y, routed = M.moe_dropless(p["moe"], h, cfg)
     if spec.ffn == "moe_dense":  # arctic: routed experts + parallel dense MLP
-        return x + M.moe_apply(p["moe"], h, cfg) + mlp_apply(p["dense_mlp"], h, cfg)
-    raise ValueError(spec.ffn)
+        y = y + mlp_apply(p["dense_mlp"], h, cfg)
+    return x + y, routed
 
 
 def _apply_block(
@@ -177,6 +197,19 @@ def _apply_block(
     cross: bool = False,
 ):
     """Returns (x, new_cache_or_None)."""
+    with jax.named_scope(spec.mixer):
+        x, new_cache = _apply_mixer(spec, p, x, cfg, mode=mode, cache=cache, pos=pos,
+                                    enc_out=enc_out, causal=causal, cross=cross)
+    with jax.named_scope(spec.ffn):
+        x, routed = _apply_ffn(spec, p, x, cfg, mode)
+    if routed is not None:
+        new_cache["routed"] = routed
+    x = hint(x, "batch", "seq", None)
+    return x, (new_cache if new_cache else None)
+
+
+def _apply_mixer(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, *, mode: str,
+                 cache, pos, enc_out, causal: bool, cross: bool):
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     new_cache: dict[str, Any] = {}
     if spec.mixer in ("attn", "attn_local"):
@@ -231,10 +264,7 @@ def _apply_block(
         x = x + y
     else:
         raise ValueError(spec.mixer)
-
-    x = _apply_ffn(spec, p, x, cfg)
-    x = hint(x, "batch", "seq", None)
-    return x, (new_cache if new_cache else None)
+    return x, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -381,6 +411,17 @@ def decode_step(params, cfg: ModelConfig, token, pos, caches):
         cross=cfg.is_encdec,
     )
     return _head(params, x, cfg), new_caches
+
+
+def expert_tokens(cfg: ModelConfig, caches) -> jax.Array:
+    """(expert layers, experts held) int32, layers in stack order: the
+    (token, choice) pairs that the step which wrote ``caches`` routed to
+    each held expert, summed over the batch; (0, 0) for a model without
+    experts."""
+    per_pos = [c["routed"] for c in caches if c and "routed" in c]  # (n_sb, batch, held)
+    if not per_pos:
+        return jnp.zeros((0, 0), jnp.int32)
+    return jnp.stack(per_pos, axis=1).sum(axis=2).reshape(-1, cfg.held_experts)
 
 
 def loss_fn(params, cfg: ModelConfig, batch: dict):

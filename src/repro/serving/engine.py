@@ -41,7 +41,16 @@ The jitted programs are named ``engine_prefill`` and ``engine_decode``
 (``jit_engine_prefill(...)`` and ``jit_engine_decode(...)`` in a profile).
 Each picks the greedy next token itself: it returns the ``argmax`` of its
 logits as int32 ids, never the logits, so sampling dispatches nothing on
-the host.
+the host. The decode program also returns, per expert layer, how many of
+the step's (token, choice) pairs went to each held expert, read from the
+caches the step wrote (``lm.expert_tokens``); the engine keeps that device
+array as ``Engine.expert_tokens`` and never copies it to the host (shape
+(0, 0) for a model without experts).
+
+KV caches and recurrent state (Mamba's ``conv`` and ``h``) live side by
+side in the slot caches: ``_write_slot`` copies a prompt's keys and values
+into its slot's first positions, and state leaves (and an expert layer's
+``routed`` counts) whole.
 """
 
 from __future__ import annotations
@@ -145,7 +154,8 @@ class Engine:
         # program keeps only the greedy ids: (slots,) and (1,) int32.
         def engine_decode(p, tok, pos, caches):
             logits, caches = lm.decode_step(p, cfg, tok, pos, caches)
-            return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), caches
+            ids = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            return ids, caches, lm.expert_tokens(cfg, caches)
 
         def engine_prefill(p, tokens):
             logits, caches = lm.prefill(p, cfg, tokens)
@@ -156,6 +166,7 @@ class Engine:
         # slot state
         B, S = sc.slots, sc.max_seq
         self.caches = self._zero_caches(B, S)
+        self.expert_tokens = lm.expert_tokens(cfg, self.caches)
         self.positions = np.zeros(B, np.int32)  # next position per slot
         self.active: list[Request | None] = [None] * B
         self.remaining = np.zeros(B, np.int32)
@@ -326,7 +337,7 @@ class Engine:
                                     self.caches)
 
             start = _stamp(now)
-            (next_ids, new_caches), dt = self._timed(
+            (next_ids, new_caches, self.expert_tokens), dt = self._timed(
                 "decode", run, tokens=n_active, occupancy=n_active)
             self.caches = new_caches
             self._warm_decode = True
