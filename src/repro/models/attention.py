@@ -7,7 +7,8 @@ Three execution paths:
   * decode: one query token against a cache. Global layers use an append
     cache; local (sliding-window) layers use a ring buffer of size W whose
     slot->absolute-position mapping is computed analytically (no stored
-    position tensor). Split-KV decode maps to sequence-sharded caches.
+    position tensor). The step writes its one K/V row into the caches of the
+    whole stack in place. Split-KV decode maps to sequence-sharded caches.
   * cross-attention (enc-dec): queries against cached encoder K/V.
 """
 
@@ -225,10 +226,16 @@ def attn_decode(
     pos: jax.Array,
     cfg: ModelConfig,
     *,
+    layer,
     local: bool = False,
 ):
     """x: (B, 1, d); pos: scalar int32 — the absolute position of this token.
-    Returns (y, new_cache)."""
+
+    ``cache`` holds the K/V of every layer of the stack, ``(layers, B, S,
+    K, hd)``, and ``layer`` is this layer's index in it. The token's K/V row
+    is written into the stacked buffers in place, and the attention reads
+    this layer's keys and values from them, so no layer-sized copy is made.
+    Returns (y, the stacked cache with the row written)."""
     B = x.shape[0]
     K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     H = cfg.num_heads
@@ -241,26 +248,28 @@ def attn_decode(
         q = rope_apply(q.reshape(B, 1, H, hd), pos_arr, cfg.rope_theta).reshape(B, 1, K, G, hd)
         k_new = rope_apply(k_new, pos_arr, cfg.rope_theta)
 
-    S_c = cache["k"].shape[1]
+    S_c = cache["k"].shape[2]
     if local:
         slot = jnp.mod(pos, cfg.window_size)
-        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
         # slot i holds the latest position <= pos congruent to i (mod W);
         # negative -> never written.
         i = jnp.arange(S_c, dtype=jnp.int32)
         slot_pos = pos - jnp.mod(pos - i, cfg.window_size)
         valid = slot_pos >= 0
     else:
-        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, pos, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, pos, axis=1)
+        slot = pos
         valid = jnp.arange(S_c, dtype=jnp.int32) <= pos
+
+    ks = jax.lax.dynamic_update_slice(cache["k"], k_new[None], (layer, 0, slot, 0, 0))
+    vs = jax.lax.dynamic_update_slice(cache["v"], v_new[None], (layer, 0, slot, 0, 0))
+    k = jax.lax.dynamic_index_in_dim(ks, layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(vs, layer, keepdims=False)
 
     scale = hd**-0.5
     mask = valid[None, None, None, None, :]  # (1,1,1,1,Sk)
     out = _sdpa_block(q, k, v, mask=mask, cap=cfg.attn_softcap, scale=scale)
     y = out.reshape(B, 1, H * hd) @ p["wo"]
-    return y, {"k": k, "v": v}
+    return y, {"k": ks, "v": vs}
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,10 @@ compiles).
 Modes:
   forward  — full-sequence logits (training)
   prefill  — full-sequence + build decode caches
-  decode   — one token, consume/update caches
+  decode   — one token, update the caches: the stacked caches are the layer
+             loop's carry, and each layer writes its one K/V row (or its
+             whole recurrent state) at its own index, so a caller that
+             donates them has them updated in place
 
 Expert layers route with capacity in ``forward`` (``moe.moe_apply``) and
 without drops in ``prefill`` and ``decode`` (``moe.moe_dropless``, which
@@ -195,27 +198,51 @@ def _apply_block(
     enc_out,
     causal: bool,
     cross: bool = False,
+    layer=None,
 ):
-    """Returns (x, new_cache_or_None)."""
+    """Returns (x, new_cache_or_None). In decode mode ``cache`` holds the
+    caches of every layer of the stack at this superblock position and
+    ``layer`` is this layer's index in them; the returned caches are the
+    stack's, with this layer's written (:func:`_store`)."""
     with jax.named_scope(spec.mixer):
         x, new_cache = _apply_mixer(spec, p, x, cfg, mode=mode, cache=cache, pos=pos,
-                                    enc_out=enc_out, causal=causal, cross=cross)
+                                    enc_out=enc_out, causal=causal, cross=cross, layer=layer)
     with jax.named_scope(spec.ffn):
         x, routed = _apply_ffn(spec, p, x, cfg, mode)
     if routed is not None:
         new_cache["routed"] = routed
     x = hint(x, "batch", "seq", None)
+    if mode == "decode":
+        return x, _store(cache, new_cache, layer)
     return x, (new_cache if new_cache else None)
 
 
+def _store(stacked: dict, written: dict, layer) -> dict:
+    """The stack's caches after one decode layer wrote ``written``.
+
+    A K/V leaf comes back as the stacked buffer, its one new row already
+    written in place (``attn_decode``), and is taken as it is. A state leaf
+    (Mamba ``conv``/``h``, xLSTM states, an expert layer's ``routed``) comes
+    back as the layer's new value, which replaces its slice whole. A leaf the
+    layer only reads (the encoder's ``cross_k``/``cross_v``) is not written.
+    """
+    out = dict(stacked)
+    for k, v in written.items():
+        full = stacked[k]
+        out[k] = v if v.ndim == full.ndim else jax.lax.dynamic_update_index_in_dim(full, v, layer, 0)
+    return out
+
+
 def _apply_mixer(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, *, mode: str,
-                 cache, pos, enc_out, causal: bool, cross: bool):
+                 cache, pos, enc_out, causal: bool, cross: bool, layer=None):
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     new_cache: dict[str, Any] = {}
+    if mode == "decode":  # this layer's slices, read where used (XLA drops the rest)
+        state = {k: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False) for k, c in cache.items()}
     if spec.mixer in ("attn", "attn_local"):
         local = spec.mixer == "attn_local"
         if mode == "decode":
-            y, kv = A.attn_decode(p["attn"], h, {"k": cache["k"], "v": cache["v"]}, pos, cfg, local=local)
+            y, kv = A.attn_decode(p["attn"], h, cache, pos, cfg, layer=layer, local=local)
             new_cache.update(kv)
         elif mode == "prefill":
             y, (k, v) = A.attn_forward(p["attn"], h, cfg, causal=causal, local=local, return_kv=True)
@@ -226,15 +253,15 @@ def _apply_mixer(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, *, mo
         if cross:
             hc = rms_norm(x, p["norm_cross"], cfg.norm_eps)
             if mode == "decode":
-                ck, cv = cache["cross_k"], cache["cross_v"]
+                ck, cv = state["cross_k"], state["cross_v"]
             else:
                 ck, cv = A.cross_kv(p["cross"], enc_out, cfg)
             x = x + A.cross_attn_forward(p["cross"], hc, ck, cv, cfg)
-            if mode in ("prefill", "decode"):
+            if mode == "prefill":
                 new_cache["cross_k"], new_cache["cross_v"] = ck, cv
     elif spec.mixer == "mamba":
         if mode == "decode":
-            y, c = SSM.mamba_decode(p["mamba"], h, cache, cfg)
+            y, c = SSM.mamba_decode(p["mamba"], h, state, cfg)
             new_cache.update(c)
         elif mode == "prefill":
             y, c = SSM.mamba_forward(p["mamba"], h, cfg, return_cache=True)
@@ -244,7 +271,7 @@ def _apply_mixer(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, *, mo
         x = x + y
     elif spec.mixer == "mlstm":
         if mode == "decode":
-            y, c = XL.mlstm_decode(p["mlstm"], h, cache, cfg)
+            y, c = XL.mlstm_decode(p["mlstm"], h, state, cfg)
             new_cache.update(c)
         elif mode == "prefill":
             y, c = XL.mlstm_forward(p["mlstm"], h, cfg, return_cache=True)
@@ -254,7 +281,7 @@ def _apply_mixer(spec: LayerSpec, p: dict, x: jax.Array, cfg: ModelConfig, *, mo
         x = x + y
     elif spec.mixer == "slstm":
         if mode == "decode":
-            y, c = XL.slstm_decode(p["slstm"], h, cache, cfg)
+            y, c = XL.slstm_decode(p["slstm"], h, state, cfg)
             new_cache.update(c)
         elif mode == "prefill":
             y, c = XL.slstm_forward(p["slstm"], h, cfg, return_cache=True)
@@ -292,11 +319,11 @@ def _run_stack(
     # Remat at PER-LAYER granularity (not per-superblock): jamba's 8-layer
     # superblock would otherwise hold every layer's recompute transients
     # simultaneously during the superblock's backward (measured 75 GiB).
-    def layer_fn(spec_idx, lp, x, lc):
+    def layer_fn(spec_idx, lp, x, lc, layer):
         spec = superblock[spec_idx]
         return _apply_block(
             spec, lp, x, cfg, mode=mode, cache=lc, pos=pos,
-            enc_out=enc_out, causal=causal, cross=cross,
+            enc_out=enc_out, causal=causal, cross=cross, layer=layer,
         )
 
     if mode != "decode" and cfg.remat == "full":
@@ -308,31 +335,45 @@ def _run_stack(
             policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
         )
 
-    def body_fn(x, block_params, block_caches):
+    def body_fn(x, block_params, block_caches, layer=None):
         new_caches = []
         for i, _spec in enumerate(superblock):
             c = block_caches[i] if block_caches is not None else None
-            x, nc = layer_fn(i, block_params[i], x, c)
+            x, nc = layer_fn(i, block_params[i], x, c, layer)
             new_caches.append(nc)
         return x, tuple(new_caches)
 
-    emit_cache = mode in ("prefill", "decode")
-    if cfg.scan_layers:
-        xs = (blocks_params, caches) if caches is not None else (blocks_params,)
+    if mode == "decode":
+        # The stacked caches ride in the loop's carry: each layer writes its
+        # K/V row and its state into them at its own index, so a donated
+        # cache is updated in place and never copied out of the stack.
+        def decode_body(carry, xs_t):
+            x, caches = carry
+            bp, layer = xs_t
+            return body_fn(x, bp, caches, layer), None
 
-        def scan_body(carry, xs_t):
-            bp = xs_t[0]
-            bc = xs_t[1] if len(xs_t) > 1 else None
-            y, ncs = body_fn(carry, bp, bc)
+        if cfg.scan_layers:
+            (x, caches), _ = jax.lax.scan(
+                decode_body, (x, caches), (blocks_params, jnp.arange(n_sb, dtype=jnp.int32)))
+        else:
+            for sb in range(n_sb):
+                bp = jax.tree.map(lambda l: l[sb], blocks_params)
+                (x, caches), _ = decode_body((x, caches), (bp, sb))
+        return x, caches
+
+    emit_cache = mode == "prefill"
+    if cfg.scan_layers:
+
+        def scan_body(carry, bp):
+            y, ncs = body_fn(carry, bp, None)
             return y, (ncs if emit_cache else None)
 
-        x, new_caches = jax.lax.scan(scan_body, x, xs)
+        x, new_caches = jax.lax.scan(scan_body, x, blocks_params)
     else:
         new_list = []
         for sb in range(n_sb):
             bp = jax.tree.map(lambda l: l[sb], blocks_params)
-            bc = jax.tree.map(lambda l: l[sb], caches) if caches is not None else None
-            x, ncs = body_fn(x, bp, bc)
+            x, ncs = body_fn(x, bp, None)
             new_list.append(ncs)
         if emit_cache:
             new_caches = jax.tree.map(lambda *ls: jnp.stack(ls), *new_list)

@@ -45,7 +45,9 @@ the host. The decode program also returns, per expert layer, how many of
 the step's (token, choice) pairs went to each held expert, read from the
 caches the step wrote (``lm.expert_tokens``); the engine keeps that device
 array as ``Engine.expert_tokens`` and never copies it to the host (shape
-(0, 0) for a model without experts).
+(0, 0) for a model without experts). The decode program donates the slot
+caches: each step writes one K/V row per layer into them in place, and
+``tick`` replaces ``Engine.caches`` with the program's output.
 
 KV caches and recurrent state (Mamba's ``conv`` and ``h``) live side by
 side in the slot caches: ``_write_slot`` copies a prompt's keys and values
@@ -67,7 +69,7 @@ from jax.profiler import TraceAnnotation as _span
 from repro.configs.base import ModelConfig
 from repro.models import lm
 
-__all__ = ["Request", "ServeConfig", "ServiceEvent", "Engine"]
+__all__ = ["Request", "ServeConfig", "ServiceEvent", "Engine", "jit_programs"]
 
 
 @dataclass
@@ -121,6 +123,28 @@ class ServiceEvent(NamedTuple):
 Timer = Callable[..., tuple[Any, float]]
 
 
+def jit_programs(cfg: ModelConfig) -> tuple[Callable, Callable]:
+    """The engine's jitted ``(decode, prefill)`` programs.
+
+    ``lm.decode_step`` returns (logits (slots, 1, V), new caches) and
+    ``lm.prefill`` (logits (1, 1, V) of the last position, caches); each
+    program keeps only the greedy ids: (slots,) and (1,) int32. The decode
+    donates its caches (argument 3), so the step writes its K/V rows and
+    states into the same buffers and allocates no second cache.
+    """
+
+    def engine_decode(p, tok, pos, caches):
+        logits, caches = lm.decode_step(p, cfg, tok, pos, caches)
+        ids = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        return ids, caches, lm.expert_tokens(cfg, caches)
+
+    def engine_prefill(p, tokens):
+        logits, caches = lm.prefill(p, cfg, tokens)
+        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), caches
+
+    return jax.jit(engine_decode, donate_argnums=(3,)), jax.jit(engine_prefill)
+
+
 def _stamp(now: float | None, charged: float = 0.0) -> float:
     """An event's time: the wall clock as it happens when the engine owns
     the clock (``now is None``), else the caller's ``now`` plus the service
@@ -149,20 +173,7 @@ class Engine:
         self.tracer = tracer
         self._trace = tracer is not None and getattr(tracer, "enabled", True)
 
-        # lm.decode_step returns (logits (slots, 1, V), new caches) and
-        # lm.prefill (logits (1, 1, V) of the last position, caches); each
-        # program keeps only the greedy ids: (slots,) and (1,) int32.
-        def engine_decode(p, tok, pos, caches):
-            logits, caches = lm.decode_step(p, cfg, tok, pos, caches)
-            ids = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            return ids, caches, lm.expert_tokens(cfg, caches)
-
-        def engine_prefill(p, tokens):
-            logits, caches = lm.prefill(p, cfg, tokens)
-            return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), caches
-
-        self._decode = jax.jit(engine_decode)
-        self._prefill = jax.jit(engine_prefill)
+        self._decode, self._prefill = jit_programs(cfg)
         # slot state
         B, S = sc.slots, sc.max_seq
         self.caches = self._zero_caches(B, S)
@@ -207,7 +218,8 @@ class Engine:
         JAX specialises ``prefill`` per prompt length, so pass every length
         the workload can draw. Compile-time is the dominant first-call cost
         (seconds vs millisecond service times) and would otherwise pollute
-        any measured mean. Runs on scratch inputs; engine state is untouched.
+        any measured mean. Runs on scratch inputs; engine state is untouched
+        (the decode donates its caches, so it runs on scratch zero caches).
         """
         for L in sorted({int(x) for x in prompt_lens}):
             if L in self._warm_prefill:
@@ -217,8 +229,8 @@ class Engine:
             self._warm_prefill.add(L)
         if decode and not self._warm_decode:
             tok = jnp.zeros((self.sc.slots, 1), jnp.int32)
-            jax.block_until_ready(
-                self._decode(self.params, tok, jnp.int32(0), self.caches))
+            scratch = self._zero_caches(self.sc.slots, self.sc.max_seq)
+            jax.block_until_ready(self._decode(self.params, tok, jnp.int32(0), scratch))
             self._warm_decode = True
 
     # ------------------------------------------------------------------

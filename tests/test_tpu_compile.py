@@ -12,6 +12,7 @@ every test worker imports every test file.
 """
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -152,3 +153,52 @@ def test_fleet_tail_euler_compiles_without_complex(one_chip):
             proc_hint=_uniform_kind_hint(cols["edge_model"]))
         assert "complex" not in lowered.as_text()
         lowered.compile()
+
+
+LAYER_CACHE = re.compile(r"^(?:ROOT )?%\S+ = bf16\[(?:1,)?1,4096,32,128\]")
+
+
+def _unfused_instructions(hlo: str) -> list[str]:
+    """Instructions of a compiled module's text that lie outside the
+    computations its fusions call."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name and line.strip() not in ("", "}"):
+            comps[name].append(line.strip())
+    fused = {c for lines in comps.values() for l in lines if " fusion(" in l
+             for c in re.findall(r"calls=%([\w.\-]+)", l)}
+    assert fused, "no fusion found: the module text is not what this parser reads"
+    return [l for c, lines in comps.items() if c not in fused for l in lines]
+
+
+def test_engine_decode_writes_its_cache_in_place(one_chip):
+    """The engine's decode at deepseek_7b_15l's widths (15 of 30 layers, one
+    slot of 4096 positions): the donated caches alias the output whole, the
+    program needs less scratch than one layer's K+V, and no layer-sized
+    copy of the cache exists outside a fusion (the attention einsums read
+    the stacked buffer)."""
+    from dataclasses import replace
+
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.models.params import abstract_params
+    from repro.serving.engine import jit_programs
+
+    cfg = replace(get_config("deepseek_7b"), num_superblocks=15)
+    params = _on(one_chip, lm.abstract_model(cfg))
+    caches = _on(one_chip, abstract_params(lm.cache_template(cfg, 1, 4096),
+                                           jnp.dtype(cfg.dtype)))
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jit_programs(cfg)[0].lower(params, tok, pos, caches).compile()
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    layer_kv = 2 * 4096 * cfg.kv_dim * 2
+    assert cache_bytes == 15 * layer_kv == 1_006_632_960
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < layer_kv
+    assert not [l for l in _unfused_instructions(compiled.as_text()) if LAYER_CACHE.match(l)]
