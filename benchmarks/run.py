@@ -21,7 +21,6 @@ JSON artifact under ``--out``:
                          enabled-tracer tokens/s, audit rows/s + re-sum gate)
   * ``plan``          -> BENCH_plan.json (provisioning-solver wall time,
                          equilibrium solves vs grid size, plan picked)
-  * ``roofline``      -> CSV rows from dry-run artifacts, when present
 
 Every BENCH_*.json written by a run gets a ``manifest`` block stamped in
 (``repro.obs.run_manifest``: seed-free provenance — git sha, config hash,
@@ -66,16 +65,6 @@ def run_paper_figures(out_dir: Path) -> dict:
     }
     (out_dir / "BENCH_paper_figures.json").write_text(json.dumps(report, indent=2))
     return report
-
-
-def run_roofline(out_dir: Path) -> dict:
-    # roofline table from dry-run artifacts, if present
-    roof = Path("experiments/roofline")
-    if roof.is_dir() and any(roof.glob("*.json")):
-        from .roofline_report import print_roofline_rows
-
-        print_roofline_rows(roof)
-    return {}
 
 
 def _family_runner(payload: str):

@@ -18,16 +18,12 @@ have no separate FFN).
 
 from __future__ import annotations
 
-import enum
 import importlib
-from dataclasses import dataclass, field, replace
-from typing import Sequence
+from dataclasses import dataclass, replace
 
 __all__ = [
     "LayerSpec",
     "ModelConfig",
-    "ShapeSuite",
-    "SHAPES",
     "register",
     "get_config",
     "list_configs",
@@ -91,19 +87,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
-    remat: str = "full"  # "none" | "full" | "dots"
-    scan_layers: bool = True  # False unrolls superblocks (roofline accounting)
     seq_chunk: int = 512  # query-chunk for the XLA flash-style attention
-    unroll_attn_chunks: bool = False  # True for roofline-accounting compiles
-    attn_impl: str = "xla"  # "xla" | "pallas" (TPU)
-    seq_parallel: str = "auto"  # "auto" | "on" | "off" (Megatron-SP residual)
-    optimizer: str = "adamw"  # "adamw" | "adafactor" (480B-class memory)
-    grad_accum: int = 1  # microbatches per step (activation memory lever)
-    grad_dtype: str = "float32"  # gradient accumulation dtype
     # --- paper linkage ---
     service_model: str = "md1"  # queueing formulation (md1 dense | mm1 variable)
-    # --- shape policy ---
-    supports_long_context: bool = False  # run long_500k?
     notes: str = ""
 
     # ------------------------------------------------------------------
@@ -181,32 +167,12 @@ class ModelConfig:
             mamba_d_conv=4,
             encoder_layers=2 if self.encoder_layers else 0,
             seq_chunk=16,
-            grad_accum=1,
-            grad_dtype="float32",
-            remat="none",
             dtype="float32",
             name=self.name + "-smoke",
         )
         small.update(overrides)
         return replace(self, **small)
 
-
-@dataclass(frozen=True)
-class ShapeSuite:
-    """One assigned input-shape cell."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES: dict[str, ShapeSuite] = {
-    "train_4k": ShapeSuite("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeSuite("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeSuite("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeSuite("long_500k", 524_288, 1, "decode"),
-}
 
 ARCH_IDS = [
     "starcoder2_15b",
@@ -249,10 +215,3 @@ def list_configs() -> list[str]:
             pass
     return sorted(_REGISTRY)
 
-
-def shape_cells(cfg: ModelConfig) -> list[ShapeSuite]:
-    """The shape cells this arch runs (long_500k only for sub-quadratic archs)."""
-    cells = [SHAPES["train_4k"], SHAPES["prefill_32k"], SHAPES["decode_32k"]]
-    if cfg.supports_long_context:
-        cells.append(SHAPES["long_500k"])
-    return cells

@@ -10,8 +10,6 @@ CONFIG = register(ModelConfig(
     num_experts=16, num_experts_per_tok=4, capacity_factor=1.25,
     moe_group_size=1024,  # 16e x top-4 makes E*C fat; smaller groups bound the dispatch tensor
     rope=True,
-    optimizer="adafactor", grad_accum=4,
     service_model="mm1",
-    supports_long_context=False,
     notes="40L; MoE-16 top-4.",
 ))

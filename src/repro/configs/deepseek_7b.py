@@ -9,6 +9,5 @@ CONFIG = register(ModelConfig(
     superblock=(LayerSpec("attn", "mlp"),), num_superblocks=30,
     rope=True,
     service_model="mm1",
-    supports_long_context=False,
     notes="30L MHA (kv=32); llama-style SwiGLU MLP.",
 ))

@@ -13,9 +13,6 @@ CONFIG = register(ModelConfig(
     mlp_act="gelu",
     attn_softcap=50.0, final_softcap=30.0,
     tie_embeddings=True,
-    grad_accum=2,
     service_model="mm1",
-    # half the stack is window-4096; global layers keep full KV (DESIGN.md S4)
-    supports_long_context=True,
     notes="42L alternating local(4096)/global attention; attn softcap 50, final 30.",
 ))

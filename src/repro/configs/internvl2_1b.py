@@ -14,6 +14,5 @@ CONFIG = register(ModelConfig(
     prefix_embed=True, prefix_len_fraction=1.0 / 16.0,
     rope=True,
     service_model="mm1",
-    supports_long_context=False,
     notes="24L GQA kv=2; 1/16 of seq is stubbed patch-embedding prefix.",
 ))

@@ -3,7 +3,7 @@
 Period-8 superblock with attention at index 4 and MoE on odd layers (16
 experts top-2), matching the published Jamba block layout. Attention layers
 use no positional embedding (NoPE) as in the paper. KV state exists only on
-the 4 attention layers -> long_500k runs.
+the 4 attention layers.
 """
 from .base import LayerSpec, ModelConfig, register
 
@@ -24,8 +24,6 @@ CONFIG = register(ModelConfig(
     rope=False,  # Jamba uses NoPE on its attention layers
     mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
     mamba_dt_rank=256, mamba_inner_norms=True,  # dt/B/C RMSNorms of JambaMambaMixer
-    grad_accum=8,  # measured: temp 18.7 GiB at accum 4 -> 13.6 at 8 (fits 16 GiB HBM)
     service_model="mm1",
-    supports_long_context=True,
     notes="32L = 4 x 8(1 attn : 7 mamba, MoE every other layer).",
 ))

@@ -16,6 +16,5 @@ CONFIG = register(ModelConfig(
     prefix_embed=True,  # encoder takes precomputed frame embeddings
     rope=True,
     service_model="mm1",
-    supports_long_context=False,
     notes="enc-dec; encoder bidirectional over stubbed audio-frame embeddings.",
 ))

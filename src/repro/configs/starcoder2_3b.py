@@ -10,6 +10,5 @@ CONFIG = register(ModelConfig(
     rope=True,
     gated_mlp=False, mlp_act="gelu",
     service_model="mm1",
-    supports_long_context=False,
     notes="30L GQA kv=2; full causal attention.",
 ))

@@ -2,7 +2,7 @@
 
 48 blocks as 6 x (1 sLSTM + 7 mLSTM) following the paper's a:b block-ratio
 notation; blocks carry their own up/down projections (d_ff=0 -> no separate
-FFN). O(1) recurrent state -> long_500k runs.
+FFN). O(1) recurrent state.
 """
 from .base import LayerSpec, ModelConfig, register
 
@@ -20,8 +20,6 @@ CONFIG = register(ModelConfig(
     ),
     num_superblocks=6,
     rope=False,
-    grad_accum=2,
     service_model="mm1",  # length-dependent recurrence: the paper's RNN case
-    supports_long_context=True,
     notes="48 blocks = 6 x (sLSTM + 7 mLSTM); constant-size recurrent state.",
 ))

@@ -65,10 +65,10 @@ def from_roofline(
 
     s = max(flops/peak, bytes/bw, collective_s) / efficiency
 
-    This is the TPU-native replacement for GPU profiling: the dry-run's
-    ``compiled.cost_analysis()`` supplies flops/bytes and the HLO collective
-    parse supplies collective_s (see repro.perf.roofline). ``efficiency``
-    discounts peak to a realistic fraction (MFU-style).
+    This is the TPU-native replacement for GPU profiling: a compiled
+    program's ``cost_analysis()`` can supply flops/bytes, and the time of
+    its collectives collective_s. ``efficiency`` discounts peak to a
+    realistic fraction (MFU-style).
     """
     if peak_flops <= 0 or hbm_bw <= 0 or not 0 < efficiency <= 1:
         raise ValueError("invalid hardware constants")

@@ -93,7 +93,7 @@ def call_payload(fn: Callable, out_dir: Path, *, seed: int,
 
     Bench families keep their historical ``fn(out_dir) -> report`` shape;
     seed-sensitive payloads take ``fn(out_dir, seed=..., config=...)``. A
-    payload returning ``None`` contributes no metrics (roofline).
+    payload returning ``None`` contributes no metrics.
     """
     params = inspect.signature(fn).parameters
     kwargs = {}

@@ -224,16 +224,6 @@ def registry() -> dict[str, ExperimentSpec]:
             "tracer.enabled_overhead_pct", "audit.rows_per_sec")),
         _bench("plan", "benchmarks.plan_bench:plan_rows",
                volatile=("solver.wall_s",)),
-        # roofline emits CSV rows from pre-existing dry-run artifacts and
-        # writes nothing of its own -> empty output contract
-        ExperimentSpec(
-            exp_id="bench-roofline",
-            kind="bench-family",
-            payload="benchmarks.run:run_roofline",
-            description="roofline table from experiments/roofline dry-run "
-                        "artifacts, when present (no artifact of its own)",
-            config={"family": "roofline"},
-        ),
         # -- hardware in the loop ---------------------------------------------
         ExperimentSpec(
             exp_id="measured-smoke",

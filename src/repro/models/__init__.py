@@ -5,7 +5,6 @@ from .lm import (
     decode_step,
     forward,
     init_model,
-    loss_fn,
     model_param_axes,
     model_template,
     num_params,
@@ -15,6 +14,6 @@ from .lm import (
 __all__ = [
     "attention", "layers", "lm", "moe", "params", "ssm", "xlstm",
     "abstract_model", "cache_template", "decode_step", "forward",
-    "init_model", "loss_fn", "model_param_axes", "model_template",
+    "init_model", "model_param_axes", "model_template",
     "num_params", "prefill",
 ]

@@ -161,19 +161,8 @@ def attn_forward(
         mask = _mask_block(q_pos, key_positions, causal=causal, window=window)
         return _sdpa_block(qc, k, v, mask=mask[None, None, None], cap=cfg.attn_softcap, scale=scale)
 
-    if cfg.remat != "none":
-        # flash-style backward: recompute chunk scores instead of saving the
-        # (chunk x S) probability tensor per chunk across the scan
-        one_chunk = jax.checkpoint(one_chunk)
-
     if n_chunks == 1:
         out = one_chunk(q, jnp.int32(0))
-    elif cfg.unroll_attn_chunks:
-        outs = [
-            one_chunk(q[:, i * chunk : (i + 1) * chunk], jnp.int32(i * chunk))
-            for i in range(n_chunks)
-        ]
-        out = jnp.concatenate(outs, axis=1)
     else:
         qs = q.reshape(B, n_chunks, chunk, K, G, hd).transpose(1, 0, 2, 3, 4, 5)
         offs = jnp.arange(n_chunks, dtype=jnp.int32) * chunk

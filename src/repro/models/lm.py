@@ -2,13 +2,11 @@
 
 One code path serves all 10 assigned architectures; the superblock pattern in
 the config decides which mixers/FFNs appear. The stack is scanned over
-superblocks (HLO O(1) in depth); ``cfg.scan_layers=False`` unrolls it for the
-roofline-accounting compiles (XLA's cost analysis counts a while-loop body
-once, so totals are extrapolated from unrolled 1- and 2-superblock
-compiles).
+superblocks (HLO O(1) in depth).
 
 Modes:
-  forward  — full-sequence logits (training)
+  forward  — full-sequence logits (the reference the served modes are
+             tested against)
   prefill  — full-sequence + build decode caches
   decode   — one token, update the caches: the stacked caches are the layer
              loop's carry, and each layer writes its one K/V row (or its
@@ -29,7 +27,6 @@ without changing what it returns (:func:`expert_tokens`).
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Any
 
 import jax
@@ -54,7 +51,6 @@ __all__ = [
     "forward",
     "prefill",
     "decode_step",
-    "loss_fn",
     "encode",
     "expert_tokens",
 ]
@@ -311,35 +307,17 @@ def _run_stack(
     causal: bool = True,
     cross: bool = False,
     superblock=None,
-    n_superblocks=None,
 ):
     superblock = superblock or cfg.superblock
-    n_sb = n_superblocks or cfg.num_superblocks
-
-    # Remat at PER-LAYER granularity (not per-superblock): jamba's 8-layer
-    # superblock would otherwise hold every layer's recompute transients
-    # simultaneously during the superblock's backward (measured 75 GiB).
-    def layer_fn(spec_idx, lp, x, lc, layer):
-        spec = superblock[spec_idx]
-        return _apply_block(
-            spec, lp, x, cfg, mode=mode, cache=lc, pos=pos,
-            enc_out=enc_out, causal=causal, cross=cross, layer=layer,
-        )
-
-    if mode != "decode" and cfg.remat == "full":
-        layer_fn = jax.checkpoint(layer_fn, static_argnums=(0,))
-    elif mode != "decode" and cfg.remat == "dots":
-        layer_fn = jax.checkpoint(
-            layer_fn,
-            static_argnums=(0,),
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        )
 
     def body_fn(x, block_params, block_caches, layer=None):
         new_caches = []
-        for i, _spec in enumerate(superblock):
+        for i, spec in enumerate(superblock):
             c = block_caches[i] if block_caches is not None else None
-            x, nc = layer_fn(i, block_params[i], x, c, layer)
+            x, nc = _apply_block(
+                spec, block_params[i], x, cfg, mode=mode, cache=c, pos=pos,
+                enc_out=enc_out, causal=causal, cross=cross, layer=layer,
+            )
             new_caches.append(nc)
         return x, tuple(new_caches)
 
@@ -352,33 +330,17 @@ def _run_stack(
             bp, layer = xs_t
             return body_fn(x, bp, caches, layer), None
 
-        if cfg.scan_layers:
-            (x, caches), _ = jax.lax.scan(
-                decode_body, (x, caches), (blocks_params, jnp.arange(n_sb, dtype=jnp.int32)))
-        else:
-            for sb in range(n_sb):
-                bp = jax.tree.map(lambda l: l[sb], blocks_params)
-                (x, caches), _ = decode_body((x, caches), (bp, sb))
+        layers = jnp.arange(cfg.num_superblocks, dtype=jnp.int32)
+        (x, caches), _ = jax.lax.scan(decode_body, (x, caches), (blocks_params, layers))
         return x, caches
 
     emit_cache = mode == "prefill"
-    if cfg.scan_layers:
 
-        def scan_body(carry, bp):
-            y, ncs = body_fn(carry, bp, None)
-            return y, (ncs if emit_cache else None)
+    def scan_body(carry, bp):
+        y, ncs = body_fn(carry, bp, None)
+        return y, (ncs if emit_cache else None)
 
-        x, new_caches = jax.lax.scan(scan_body, x, blocks_params)
-    else:
-        new_list = []
-        for sb in range(n_sb):
-            bp = jax.tree.map(lambda l: l[sb], blocks_params)
-            x, ncs = body_fn(x, bp, None)
-            new_list.append(ncs)
-        if emit_cache:
-            new_caches = jax.tree.map(lambda *ls: jnp.stack(ls), *new_list)
-        else:
-            new_caches = None
+    x, new_caches = jax.lax.scan(scan_body, x, blocks_params)
     return x, new_caches
 
 
@@ -418,13 +380,13 @@ def encode(params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
     x = hint(enc_embeds.astype(jnp.dtype(cfg.dtype)), "batch", "seq", None)
     x, _ = _run_stack(
         enc["blocks"], x, cfg, mode="forward", causal=False,
-        superblock=(LayerSpec("attn", "mlp"),), n_superblocks=cfg.encoder_layers,
+        superblock=(LayerSpec("attn", "mlp"),),
     )
     return rms_norm(x, enc["final_norm"], cfg.norm_eps)
 
 
 def forward(params, cfg: ModelConfig, tokens, *, prefix_embeds=None, enc_embeds=None):
-    """Full-sequence logits (training path)."""
+    """Full-sequence logits: the reference that prefill and decode are tested against."""
     enc_out = encode(params, cfg, enc_embeds) if cfg.is_encdec else None
     x = _embed(params, tokens, cfg, prefix_embeds)
     x, _ = _run_stack(params["blocks"], x, cfg, mode="forward", enc_out=enc_out,
@@ -464,28 +426,3 @@ def expert_tokens(cfg: ModelConfig, caches) -> jax.Array:
         return jnp.zeros((0, 0), jnp.int32)
     return jnp.stack(per_pos, axis=1).sum(axis=2).reshape(-1, cfg.held_experts)
 
-
-def loss_fn(params, cfg: ModelConfig, batch: dict):
-    """Next-token CE (fp32 softmax) + z-loss; honours batch['loss_mask']."""
-    logits = forward(
-        params, cfg, batch["tokens"],
-        prefix_embeds=batch.get("prefix_embeds"),
-        enc_embeds=batch.get("enc_embeds"),
-    )
-    targets = batch["targets"]
-    mask = batch["loss_mask"].astype(jnp.float32)
-    # prefix positions carry no targets; logits cover prefix + tokens
-    if logits.shape[1] != targets.shape[1]:
-        logits = logits[:, logits.shape[1] - targets.shape[1] :]
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    gold = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
-    nll = lse - gold
-    z_loss = 1e-4 * lse**2
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = jnp.sum((nll + z_loss) * mask) / denom
-    return loss, {
-        "loss": loss,
-        "nll": jnp.sum(nll * mask) / denom,
-        "tokens": mask.sum(),
-    }

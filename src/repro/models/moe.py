@@ -171,15 +171,3 @@ def moe_dropless(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
         y = jnp.where(gate > 0, y.astype(jnp.float32) * gate, 0.0)
         out = jnp.zeros((B * S, d), jnp.float32).at[tok].add(y)
     return out.astype(x.dtype).reshape(B, S, d), routed
-
-
-def router_aux_loss(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Load-balancing auxiliary loss (Switch [arXiv:2101.03961] style)."""
-    B, S, d = x.shape
-    logits = jnp.einsum("bsd,de->bse", x, p["router"], preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    counts = jax.nn.one_hot(idx, cfg.num_experts, dtype=jnp.float32).sum(axis=(0, 1, 2))
-    frac_tokens = counts / jnp.maximum(counts.sum(), 1.0)
-    frac_probs = probs.mean(axis=(0, 1))
-    return cfg.num_experts * jnp.sum(frac_tokens * frac_probs)

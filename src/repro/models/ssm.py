@@ -31,7 +31,7 @@ from .params import TSpec
 __all__ = ["mamba_template", "mamba_cache_template", "mamba_forward", "mamba_decode"]
 
 
-MAMBA_CHUNK = 128  # outer-scan chunk (state checkpointed at boundaries)
+MAMBA_CHUNK = 128  # steps per chunk of the two-level selective scan
 
 
 def mamba_template(cfg: ModelConfig) -> dict:
@@ -96,11 +96,10 @@ def _ssm_core(p: dict, u_conv: jax.Array, cfg: ModelConfig, h0: jax.Array):
         h = decay * h + inp
         return h, jnp.einsum("bdn,bn->bd", h, C_t.astype(f32))
 
-    # Two-level scan: outer over chunks (h saved at chunk boundaries only),
-    # inner per-step scan rematerialised in the backward pass. A flat
-    # 4096-step scan would checkpoint the (B, di, n) state at EVERY step —
-    # tens of GB per layer; this bounds it to S/chunk boundaries + one
-    # chunk's transient (the same trick our Pallas kernel plays with VMEM).
+    # Two-level scan: an outer scan over chunks of MAMBA_CHUNK steps carries
+    # only the (B, di, n) state from chunk to chunk, and an inner scan steps
+    # through each chunk. The served prefill's chip numbers were measured
+    # with this loop; whether one flat scan is faster is not measured.
     S = u_conv.shape[1]
     tc = min(MAMBA_CHUNK, S)
     while S % tc:
@@ -115,8 +114,6 @@ def _ssm_core(p: dict, u_conv: jax.Array, cfg: ModelConfig, h0: jax.Array):
     def chunk_body(h, xs_chunk):
         return jax.lax.scan(step, h, xs_chunk)
 
-    if cfg.remat != "none" and S > 1:
-        chunk_body = jax.checkpoint(chunk_body)
     h_final, y_cm = jax.lax.scan(chunk_body, h0, xs)  # y_cm: (nc, tc, B, di)
     y = jnp.moveaxis(y_cm.reshape(nc * tc, *y_cm.shape[2:]), 0, 1)
     y = hint(y, "batch", "seq_inner", "ff") + u_conv.astype(f32) * p["D"].astype(f32)

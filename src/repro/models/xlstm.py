@@ -158,15 +158,7 @@ def mlstm_forward(p: dict, x: jax.Array, cfg: ModelConfig, *, return_cache: bool
         return jnp.swapaxes(t.reshape(B, nc, L, *t.shape[2:]), 0, 1)
 
     xs = tuple(chunked(t) for t in (q, k, v, ilog, flog))
-    chunk_fn = _mlstm_chunk if cfg.remat == "none" else jax.checkpoint(_mlstm_chunk)
-    if cfg.unroll_attn_chunks:  # roofline-accounting compiles unroll inner scans
-        carry, outs = (C0, n0), []
-        for i in range(nc):
-            carry, hc = chunk_fn(carry, jax.tree.map(lambda t: t[i], xs))
-            outs.append(hc)
-        (C1, n1), hs = carry, jnp.stack(outs)
-    else:
-        (C1, n1), hs = jax.lax.scan(chunk_fn, (C0, n0), xs)
+    (C1, n1), hs = jax.lax.scan(_mlstm_chunk, (C0, n0), xs)
     h = jnp.swapaxes(hs, 0, 1).reshape(B, S, H, hd).astype(x.dtype)
     out = _mlstm_finish(p, h, og, cfg)
     if not return_cache:
@@ -234,9 +226,6 @@ def slstm_forward(p: dict, x: jax.Array, cfg: ModelConfig, *, return_cache: bool
         new = _slstm_step(p, cfg, carry, zt)
         return new, new[2]  # emit h
 
-    if cfg.remat != "none":
-        # save only the 4 (B,d) carries per step; gate intermediates recompute
-        step = jax.checkpoint(step)
     carry, hs = jax.lax.scan(step, init, zifo_tm)
     h = jnp.swapaxes(hs, 0, 1).astype(x.dtype)
     hn = rms_norm(h, p["headnorm"], cfg.norm_eps)

@@ -97,7 +97,6 @@ class Request:
 class ServeConfig:
     slots: int = 4  # concurrent decode slots
     max_seq: int = 512  # cache capacity per slot
-    greedy: bool = True
 
 
 class ServiceEvent(NamedTuple):

@@ -1,21 +1,21 @@
 """Logical-axis sharding: t5x-style rules mapping logical axis names to mesh axes.
 
 Model code annotates parameters and activations with *logical* axis names
-("batch", "heads", "ff", ...). At launch, a ``ShardingRules`` context resolves
-those to mesh axes and applies ``with_sharding_constraint``. Outside any
-context (CPU smoke tests) every hint is a no-op, so model code is
-mesh-agnostic.
+("batch", "heads", "ff", ...). Inside a ``use_rules`` context a
+``ShardingRules`` resolves those to mesh axes and ``hint`` applies
+``with_sharding_constraint``. Outside any context every hint is the
+identity, so model code is mesh-agnostic. No served or measured path opens
+a context yet: this is the seam a placement over several chips plugs into.
 
-Baseline rules (DESIGN.md §6): Megatron-style tensor parallelism over
-"model", batch data-parallel over ("pod", "data"), optimizer state further
-sharded over "data" (ZeRO-1, see training/optimizer.py).
+``ShardingRules.default`` is Megatron-style tensor parallelism over "model"
+and batch data parallelism over ("pod", "data").
 """
 
 from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -31,12 +31,12 @@ class ShardingRules:
     rules: dict[str, object] = field(default_factory=dict)
 
     @staticmethod
-    def default(mesh: jax.sharding.Mesh, *, seq_parallel: bool = False) -> "ShardingRules":
+    def default(mesh: jax.sharding.Mesh) -> "ShardingRules":
         has_pod = "pod" in mesh.axis_names
         batch_axes = ("pod", "data") if has_pod else ("data",)
         rules = {
             "batch": batch_axes,  # batch dim of activations / data
-            "seq": "model" if seq_parallel else None,  # residual-stream sequence dim
+            "seq": None,  # residual-stream sequence dim
             "seq_inner": None,  # interior activations (heads/ff already use model)
             "kv_seq": None,  # key/value sequence dim (cache; see decode rules)
             "embed": None,  # d_model dim of activations & params
@@ -48,19 +48,13 @@ class ShardingRules:
             "expert": "model",  # MoE expert dim
             "expert_ff": "data",  # per-expert hidden (480B-class stacks must
             # shard over data too or they exceed per-device HBM)
-            "zero": "data",  # ZeRO-1 optimizer-state axis
             "mlstm_dk": "model",  # xLSTM matrix-memory key dim
             "cache_batch": batch_axes,  # KV cache batch dim
             "cache_kv": "model",  # KV cache flattened kv feature dim
-            "cache_seq": None,  # KV cache sequence dim (long_500k: "data")
+            "cache_seq": None,  # KV cache sequence dim
             "conv_state": None,
         }
         return ShardingRules(mesh, rules)
-
-    def with_overrides(self, **overrides) -> "ShardingRules":
-        new = dict(self.rules)
-        new.update(overrides)
-        return replace(self, rules=new)
 
     def spec(self, logical_axes: tuple) -> P:
         parts = []
@@ -70,78 +64,6 @@ class ShardingRules:
             else:
                 parts.append(self.rules.get(ax))
         return P(*parts)
-
-
-def rules_for_cell(cfg, shape, mesh, *, seq_parallel: bool = False) -> ShardingRules:
-    """Resolve rules for one (arch x shape x mesh) cell, honouring divisibility.
-
-    * batch axes: the largest prefix of (pod, data) whose product divides the
-      global batch (long_500k's batch=1 shards nothing);
-    * vocab: replicated when vocab_size is not divisible by the model axis
-      (seamless 256206, internvl2 151655);
-    * decode caches: sequence-sharded over "model" (split-KV decode); for
-      unsharded-batch cells over every axis that divides the cache length.
-    """
-    sizes = dict(mesh.shape)
-    model = sizes.get("model", 1)
-    # sequence parallelism for full-sequence steps: residual stream sharded
-    # over model (Megatron-SP); decode has seq=1 so it never applies.
-    # cfg.seq_parallel: "on"/"off" overrides the heuristic (§Perf lever —
-    # prefill has no remat, so SP only buys per-layer all-gathers there).
-    sp_mode = getattr(cfg, "seq_parallel", "auto")
-    if sp_mode == "off":
-        seq_parallel = False
-    elif sp_mode == "on":
-        seq_parallel = shape.seq_len % model == 0
-    else:
-        seq_parallel = seq_parallel or (
-            shape.kind in ("train", "prefill") and shape.seq_len % model == 0
-        )
-    rules = ShardingRules.default(mesh, seq_parallel=seq_parallel)
-
-    # batch axes
-    cand = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-    batch_axes: tuple = ()
-    prod = 1
-    for ax in cand:
-        if shape.global_batch % (prod * sizes[ax]) == 0:
-            batch_axes += (ax,)
-            prod *= sizes[ax]
-    batch_rule = batch_axes if batch_axes else None
-    overrides: dict = {"batch": batch_rule, "cache_batch": batch_rule}
-
-    if cfg.padded_vocab % model:  # padded to 256-multiples; never on v5e meshes
-        overrides["vocab"] = None
-    if cfg.num_experts and cfg.d_ff % sizes.get("data", 1):
-        overrides["expert_ff"] = None
-    if cfg.num_experts and shape.kind == "decode":
-        # §Perf (jamba decode cell): expert weights sharded over "data" force
-        # a full expert-stack all-gather EVERY decode step (~11 GB/dev wire).
-        # Inference has no optimizer state, so the weights fit resident.
-        overrides["expert_ff"] = None
-
-    if shape.kind == "decode":
-        cache_axes: tuple = ()
-        cprod = 1
-        lens = [shape.seq_len]
-        if cfg.has_mixer("attn_local"):
-            lens.append(min(shape.seq_len, cfg.window_size))
-        axis_order = ("model",) if batch_axes else tuple(
-            a for a in ("pod", "data", "model") if a in mesh.axis_names
-        )
-        for ax in axis_order:
-            if all(l % (cprod * sizes[ax]) == 0 for l in lens):
-                cache_axes += (ax,)
-                cprod *= sizes[ax]
-        overrides["cache_seq"] = cache_axes if cache_axes else None
-    elif shape.kind == "prefill":
-        # emit caches already in decode layout
-        if shape.seq_len % model == 0 and (
-            not cfg.has_mixer("attn_local") or min(shape.seq_len, cfg.window_size) % model == 0
-        ):
-            overrides["cache_seq"] = "model"
-
-    return rules.with_overrides(**overrides)
 
 
 _local = threading.local()

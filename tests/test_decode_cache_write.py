@@ -25,7 +25,7 @@ JAMBA_SMALL = ModelConfig(
     d_ff=96, vocab_size=512, superblock=(LayerSpec("mamba", "moe"), LayerSpec("attn", "mlp")),
     num_superblocks=2, rope=False, num_experts=8, num_experts_per_tok=2, experts_held=4,
     moe_renormalize=False, mamba_d_state=16, mamba_dt_rank=8, mamba_inner_norms=True,
-    remat="none", seq_chunk=8, dtype="float32")
+    seq_chunk=8, dtype="float32")
 MODELS = {
     "global": lambda: get_config("starcoder2_3b").reduced(seq_chunk=8),
     "local": lambda: get_config("gemma2_9b").reduced(seq_chunk=8),  # window 8

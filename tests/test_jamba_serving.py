@@ -24,7 +24,7 @@ JAMBA = ModelConfig(
     d_ff=48, vocab_size=97, superblock=SUPERBLOCK, num_superblocks=2, rope=False,
     num_experts=8, num_experts_per_tok=2, moe_renormalize=False, mamba_d_state=4,
     mamba_dt_rank=4, mamba_inner_norms=True, capacity_factor=16.0, moe_group_size=64,
-    remat="none", seq_chunk=8, dtype="float32")
+    seq_chunk=8, dtype="float32")
 
 
 def _experts(cfg: ModelConfig, seed: int = 0) -> dict:
